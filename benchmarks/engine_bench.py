@@ -1160,7 +1160,11 @@ def run(quick: bool = False, backend: str = "vectorized",
 
     if rows_group in ("all", "engine"):
         # XLA engines cannot fork; workers>1 uses a spawn pool so the
-        # cells run concurrently, each worker with its own runtime
+        # cells run concurrently, each worker with its own runtime --
+        # unless this process holds an accelerator, where run_sweep runs
+        # them in-process (one process per chip).  Importing JAX here is
+        # what lets run_sweep see the accelerator before any child starts
+        import jax  # noqa: F401
         result = run_sweep(spec(), workers=workers or 1,
                            runner=partial(_engine_cell, quick=quick),
                            executor="spawn" if (workers or 1) > 1 else None)

@@ -36,6 +36,7 @@ from .containers import AcquireResult, Container, ContainerPool
 from .estimator import RuntimeEstimator
 from .fastpath import (
     ScanBackend,
+    ScanRejected,
     VectorizedBackend,
     cluster_scan_eligible,
     scan_bucket_timings,
@@ -178,6 +179,7 @@ __all__ = [
     "SEPT",
     "STRETCH_REFERENCE_S",
     "ScanBackend",
+    "ScanRejected",
     "SimBackend",
     "SimResult",
     "SimTrace",

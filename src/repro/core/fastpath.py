@@ -63,6 +63,7 @@ import time
 from collections import deque
 from dataclasses import dataclass, field
 from functools import partial
+from pathlib import Path
 
 import numpy as np
 
@@ -520,6 +521,15 @@ _PULL_COEF_DYN = {
     "rect": (0.0, 1.0, 1.0, 0.0, 0.0),
     "fc":   (0.0, 0.0, 0.0, 1.0, 0.0),
 }
+
+
+class ScanRejected(ValueError):
+    """A batch the scan kernel does not model, found from the cells'
+    *values* (workload, memory, dynamics schedule) rather than their static
+    flags.  The one exception the sweep layer turns into a per-cell
+    reference run (counted ``degraded``); every other error -- a compile
+    refusal, a device fault, an out-of-memory -- propagates."""
+
 
 # ClusterConfig defaults, mirrored here so scan eligibility is judged against
 # the same node sizing the reference cluster uses (tests assert they agree;
@@ -2054,9 +2064,33 @@ def _use64(flags: dict) -> bool:
 
 def _x64_ctx(use64: bool):
     if use64:
-        from jax.experimental import enable_x64
-        return enable_x64()
+        import jax
+        return jax.enable_x64(True)
     return contextlib.nullcontext()
+
+
+# JAX's persistent compilation cache.  Where JAX_COMPILATION_CACHE_DIR is set,
+# JAX reads it and nothing here overrides it; otherwise one fixed directory
+# inside the checkout (git-ignored).  The path is part of what a later
+# process must find again, so it never carries a temporary name or a PID.
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def ensure_compile_cache() -> str | None:
+    """Place JAX's persistent compilation cache before a bucket compiles and
+    return the directory in use.  Called by :func:`_build_runner`, the one
+    place every scan runner (single-shot and streamed) is compiled, so the
+    AOT ``lower().compile()`` of a bucket shape seen by an earlier process
+    loads from disk instead of compiling again."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    if (not os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            and jax.config.jax_compilation_cache_dir is None):
+        jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+        # an earlier compile in this process latched "no cache"; re-check
+        compilation_cache.reset_cache()
+    return jax.config.jax_compilation_cache_dir
 
 
 def _alloc_bucket_inputs(shape_key: tuple, bsz: int) -> dict:
@@ -2138,6 +2172,38 @@ def _alloc_bucket_inputs(shape_key: tuple, bsz: int) -> dict:
     return inp
 
 
+def _runner_kwargs(shape_key: tuple) -> tuple[dict, dict]:
+    """``(state_kw, step_kw)`` for one bucket shape: the plane packer's
+    carry-shaping flags and the event-step kernel's full static kwargs."""
+    (mask, n_req, n_nodes, n_slots, _, _, window, fc_ring, n_ep, n_copies,
+     xtra) = shape_key
+    flags = _mask_features(mask)
+    state_kw = dict(n_nodes=n_nodes, n_slots=n_slots, window=window,
+                    freeze=flags["freeze"], fc_push=flags["fc_push"],
+                    dyn=flags["dyn"], het=flags["het"],
+                    hedge=flags["hedge"], cold=flags["cold"],
+                    dup=flags["dup"], n_copies=n_copies, fc_ring=fc_ring,
+                    res=flags["res"], stream=flags["stream"])
+    step_kw = dict(state_kw, use_fc=flags["use_fc"], n_ep=n_ep,
+                   horizon=DEFAULT_FC_HORIZON, n_steps=2 * n_req + xtra)
+    return state_kw, step_kw
+
+
+def _runner_fns(shape_key: tuple):
+    """The jitted ``(init, scan)`` pair of one bucket shape, not yet lowered:
+    the vmapped plane packer and the event-step dispatch with the carry
+    planes donated."""
+    import jax
+
+    from ..kernels import ops as _kops
+
+    state_kw, step_kw = _runner_kwargs(shape_key)
+    init_fn = jax.jit(jax.vmap(partial(_make_planes, **state_kw)))
+    scan_fn = jax.jit(partial(_kops.event_step, **step_kw),
+                      donate_argnums=(0, 1))
+    return init_fn, scan_fn
+
+
 def _build_runner(shape_key: tuple, bsz: int):
     """Trace + AOT-compile the ``(init, scan)`` executable pair for one
     (bucket shape, batch size), timing the compile.  ``init`` is the vmapped
@@ -2151,23 +2217,9 @@ def _build_runner(shape_key: tuple, bsz: int):
     canonicalize the f64 specs back to f32."""
     import jax
 
-    from ..kernels import ops as _kops
-
-    (mask, n_req, n_nodes, n_slots, _, _, window, fc_ring, n_ep, n_copies,
-     xtra) = shape_key
-    flags = _mask_features(mask)
-    state_kw = dict(n_nodes=n_nodes, n_slots=n_slots, window=window,
-                    freeze=flags["freeze"], fc_push=flags["fc_push"],
-                    dyn=flags["dyn"], het=flags["het"],
-                    hedge=flags["hedge"], cold=flags["cold"],
-                    dup=flags["dup"], n_copies=n_copies, fc_ring=fc_ring,
-                    res=flags["res"], stream=flags["stream"])
-    step_kw = dict(state_kw, use_fc=flags["use_fc"], n_ep=n_ep,
-                   horizon=DEFAULT_FC_HORIZON, n_steps=2 * n_req + xtra)
-
-    init_fn = jax.jit(jax.vmap(partial(_make_planes, **state_kw)))
-    scan_fn = jax.jit(partial(_kops.event_step, **step_kw),
-                      donate_argnums=(0, 1))
+    flags = _mask_features(shape_key[0])
+    init_fn, scan_fn = _runner_fns(shape_key)
+    ensure_compile_cache()
 
     import warnings
 
@@ -2490,6 +2542,96 @@ def _scan_check_outputs(tag: str, cell_idx: int, n: int,
                 f"index {e}")
 
 
+def _fill_bucket_inputs(key: tuple, chunk: list, bsz: int) -> dict:
+    """Host input arrays for one chunk of a bucket: the idle allocation of
+    :func:`_alloc_bucket_inputs` with rows ``[:len(chunk)]`` filled from the
+    prepared cells (arrivals, fleet, policy coefficients, dynamics /
+    heterogeneity / hedging / resilience parameters and the warm seed)."""
+    (_, _, nodes_b, _, f_b, _, window, _, n_ep, _, _) = key
+    flags = _mask_features(key[0])
+    use_fc, dyn, het = flags["use_fc"], flags["dyn"], flags["het"]
+    hedge, resil = flags["hedge"], flags["res"]
+    inp = _alloc_bucket_inputs(key, bsz)
+
+    for b, cell in enumerate(chunk):
+        f = cell.feats
+        n = len(f.t)
+        inp["t"][b, :n] = f.t
+        inp["fnid"][b, :n] = f.fn_ids
+        inp["p"][b, :n] = f.p
+        inp["cost"][b, :n] = f.chan_cost
+        inp["cnt"][b, :n] = f.count
+        inp["cores"][b] = cell.cores
+        inp["nodes"][b] = cell.nodes
+        if dyn:
+            d = cell.dynamics
+            inp["act0"][b, :cell.nodes] = 0.0
+            for idx, at in d.fail:
+                # duplicate kills of one node: the earliest wins, like
+                # the reference's _do_fail no-op on an already-dead node
+                inp["killt"][b, idx] = min(inp["killt"][b, idx], at)
+            inp["dynp"][b] = (d.autoscale_interval_s,
+                              d.scale_up_queue_per_slot,
+                              d.provision_delay_s,
+                              d.failure_detect_s,
+                              1.0 if d.autoscale else 0.0)
+            inp["maxn"][b] = cell.node_cap()
+            inp["nreq"][b] = n
+        if het:
+            spd, epn, ept0, ept1, epf = cell.profile.arrays(nodes_b,
+                                                            n_ep)
+            inp["spd"][b] = spd
+            inp["epn"][b] = epn
+            inp["ept0"][b] = ept0
+            inp["ept1"][b] = ept1
+            inp["epf"][b] = epf
+        if hedge:
+            h = cell.hedging
+            inp["hmult"][b] = h.multiple
+            inp["hfloor"][b] = h.floor_s
+            inp["hmax"][b] = h.max_backups
+        if resil:
+            t4, r6, a2 = cell.resilience.arrays()
+            inp["rto_p"][b] = t4
+            inp["rrt_p"][b] = r6
+            inp["adm_p"][b] = a2
+        if cell.assignment == "pull":
+            if dyn:
+                inp["coef"][b] = _PULL_COEF_DYN[cell.policy]
+            else:
+                inp["coef"][b, :4] = _PULL_COEF[cell.policy]
+            if use_fc:
+                onehot = np.zeros((n, f_b), dtype=np.float32)
+                onehot[np.arange(n), f.fn_ids] = 1.0
+                inp["cumf"][b, 1:n + 1] = np.cumsum(onehot, axis=0)
+                inp["cumf"][b, n + 1:] = inp["cumf"][b, n]
+            for fi in range(len(f.fns)):
+                idx = np.nonzero(f.fn_ids == fi)[0]
+                inp["fn_ev"][b, fi, :idx.size] = idx
+            continue
+        inp["coef"][b, :4] = _POLICY_COEF[cell.policy]
+        if cell.assignment == "push" and cell.lb == "home":
+            from .traces import stable_hash
+            inp["route"][b] = 1
+            hashes = np.array([stable_hash(fn) for fn in f.fns],
+                              dtype=np.int64)
+            inp["home0"][b, :n] = (hashes % cell.nodes)[f.fn_ids]
+        # §V-A warm-up seeds every node's estimator with the profile
+        # median (single-node semantics at nodes=1); autoscaled nodes
+        # warm up the same way the moment they are provisioned.  The
+        # warm=False regime skips the seed: the reference only seeds
+        # estimators alongside container warm-up (warm_functions)
+        if cell.warm:
+            seed_n = min(cell.cores, window)
+            for fi, fn in enumerate(f.fns):
+                w = PROFILES[fn].median_s if fn in PROFILES else 0.1
+                inp["ring0"][b, :, fi, :seed_n] = w
+                inp["rsum0"][b, :, fi] = seed_n * w
+                inp["rlen0"][b, :, fi] = seed_n
+                inp["rpos0"][b, :, fi] = seed_n % window
+    return inp
+
+
 def _run_scan_bucket(key: tuple, cells: list[_ScanCell]) -> list[tuple]:
     """Dispatch one shape bucket in auto-tuned chunks (each padded to a
     power-of-two batch) and return per-cell ``(start, finish, prio, node,
@@ -2507,10 +2649,8 @@ def _run_scan_bucket(key: tuple, cells: list[_ScanCell]) -> list[tuple]:
     (mask, n_b, nodes_b, slots_b, f_b, kq, window, fc_ring, n_ep, n_copies,
      xtra) = key
     flags = _mask_features(mask)
-    freeze, use_fc, fc_push = (flags["freeze"], flags["use_fc"],
-                               flags["fc_push"])
-    dyn, het, hedge = flags["dyn"], flags["het"], flags["hedge"]
-    cold, dup, resil = flags["cold"], flags["dup"], flags["res"]
+    freeze, dyn, hedge = flags["freeze"], flags["dyn"], flags["hedge"]
+    cold, resil = flags["cold"], flags["res"]
     check = os.environ.get("REPRO_SCAN_CHECK") == "1"
     n1 = n_b + 1
     use64 = _use64(flags)
@@ -2676,85 +2816,7 @@ def _run_scan_bucket(key: tuple, cells: list[_ScanCell]) -> list[tuple]:
         chunk = cells[lo:lo + chunk_max]
         bsz = _pow2(len(chunk))
         t_build = time.perf_counter()
-        inp = _alloc_bucket_inputs(key, bsz)
-
-        for b, cell in enumerate(chunk):
-            f = cell.feats
-            n = len(f.t)
-            inp["t"][b, :n] = f.t
-            inp["fnid"][b, :n] = f.fn_ids
-            inp["p"][b, :n] = f.p
-            inp["cost"][b, :n] = f.chan_cost
-            inp["cnt"][b, :n] = f.count
-            inp["cores"][b] = cell.cores
-            inp["nodes"][b] = cell.nodes
-            if dyn:
-                d = cell.dynamics
-                inp["act0"][b, :cell.nodes] = 0.0
-                for idx, at in d.fail:
-                    # duplicate kills of one node: the earliest wins, like
-                    # the reference's _do_fail no-op on an already-dead node
-                    inp["killt"][b, idx] = min(inp["killt"][b, idx], at)
-                inp["dynp"][b] = (d.autoscale_interval_s,
-                                  d.scale_up_queue_per_slot,
-                                  d.provision_delay_s,
-                                  d.failure_detect_s,
-                                  1.0 if d.autoscale else 0.0)
-                inp["maxn"][b] = cell.node_cap()
-                inp["nreq"][b] = n
-            if het:
-                spd, epn, ept0, ept1, epf = cell.profile.arrays(nodes_b,
-                                                                n_ep)
-                inp["spd"][b] = spd
-                inp["epn"][b] = epn
-                inp["ept0"][b] = ept0
-                inp["ept1"][b] = ept1
-                inp["epf"][b] = epf
-            if hedge:
-                h = cell.hedging
-                inp["hmult"][b] = h.multiple
-                inp["hfloor"][b] = h.floor_s
-                inp["hmax"][b] = h.max_backups
-            if resil:
-                t4, r6, a2 = cell.resilience.arrays()
-                inp["rto_p"][b] = t4
-                inp["rrt_p"][b] = r6
-                inp["adm_p"][b] = a2
-            if cell.assignment == "pull":
-                if dyn:
-                    inp["coef"][b] = _PULL_COEF_DYN[cell.policy]
-                else:
-                    inp["coef"][b, :4] = _PULL_COEF[cell.policy]
-                if use_fc:
-                    onehot = np.zeros((n, f_b), dtype=np.float32)
-                    onehot[np.arange(n), f.fn_ids] = 1.0
-                    inp["cumf"][b, 1:n + 1] = np.cumsum(onehot, axis=0)
-                    inp["cumf"][b, n + 1:] = inp["cumf"][b, n]
-                for fi in range(len(f.fns)):
-                    idx = np.nonzero(f.fn_ids == fi)[0]
-                    inp["fn_ev"][b, fi, :idx.size] = idx
-                continue
-            inp["coef"][b, :4] = _POLICY_COEF[cell.policy]
-            if cell.assignment == "push" and cell.lb == "home":
-                from .traces import stable_hash
-                inp["route"][b] = 1
-                hashes = np.array([stable_hash(fn) for fn in f.fns],
-                                  dtype=np.int64)
-                inp["home0"][b, :n] = (hashes % cell.nodes)[f.fn_ids]
-            # §V-A warm-up seeds every node's estimator with the profile
-            # median (single-node semantics at nodes=1); autoscaled nodes
-            # warm up the same way the moment they are provisioned.  The
-            # warm=False regime skips the seed: the reference only seeds
-            # estimators alongside container warm-up (warm_functions)
-            if cell.warm:
-                seed_n = min(cell.cores, window)
-                for fi, fn in enumerate(f.fns):
-                    w = PROFILES[fn].median_s if fn in PROFILES else 0.1
-                    inp["ring0"][b, :, fi, :seed_n] = w
-                    inp["rsum0"][b, :, fi] = seed_n * w
-                    inp["rlen0"][b, :, fi] = seed_n
-                    inp["rpos0"][b, :, fi] = seed_n % window
-
+        inp = _fill_bucket_inputs(key, chunk, bsz)
         rec = {"bucket": tag, "bsz": bsz, "cells": len(chunk),
                "build_s": time.perf_counter() - t_build,
                "compile_s": 0.0, "dispatch_s": 0.0, "sync_s": 0.0}
@@ -2859,7 +2921,7 @@ def _run_scan_cells(cells: list[_ScanCell],
                     # resilience cells can terminate requests without a
                     # completion; the metrics-only fold assumes every
                     # request finished, so those cells always write back
-                    raise ValueError(
+                    raise ScanRejected(
                         "metrics_only is not supported for resilience "
                         "cells; run them through the write-back path")
                 results[i] = _cell_scan_metrics(cell, finish, extras,
@@ -2987,7 +3049,7 @@ def simulate_cells_scan(
         if validate and not scan_eligible(requests, cores, policy,
                                           warm=warm, memory_mb=memory_mb,
                                           container_mb=container_mb):
-            raise ValueError(
+            raise ScanRejected(
                 "scan backend requires the ours regime, a known policy and "
                 "(cold cells) ample container memory "
                 f"(policy={policy!r}, cores={cores}, warm={warm}); use "
@@ -3141,7 +3203,7 @@ def simulate_cluster_cells_scan(
                 lb=lb, warm=warm, memory_mb=memory_mb,
                 container_mb=container_mb, dynamics=dynamics,
                 profile=profile, hedging=hedging, resilience=resilience):
-            raise ValueError(
+            raise ScanRejected(
                 "scan cluster backend requires the ours regime with "
                 "supported dynamics/heterogeneity/hedging/resilience and, "
                 "for cold cells, ample container memory "

@@ -1045,6 +1045,7 @@ def _run_cells_scan_partial(
     per-cell cost of large grids -- a 5-policy x fleet grid generates each
     burst once instead of once per cell."""
     from .fastpath import (
+        ScanRejected,
         scan_eligible,
         simulate_cells_scan,
         simulate_cluster_cells_scan,
@@ -1092,18 +1093,20 @@ def _run_cells_scan_partial(
 
     def _dispatch(batch, runner):
         """Run ``runner`` over the whole batch; when a *value-dependent*
-        mid-dispatch rejection surfaces (an eligibility race the static
-        checks could not see), re-run cell by cell so one bad cell
-        degrades alone (``None`` -> reference fallback, counted in
-        ``degraded``) instead of sinking its entire shape bucket."""
+        rejection surfaces (:class:`~repro.core.fastpath.ScanRejected`, an
+        eligibility race the static checks could not see), re-run cell by
+        cell so one bad cell degrades alone (``None`` -> reference
+        fallback, counted in ``degraded``) instead of sinking its entire
+        shape bucket.  Any other error -- a compile refusal, a device
+        fault -- propagates: it is never turned into a reference run."""
         try:
             return runner(batch)
-        except Exception:
+        except ScanRejected:
             out = []
             for item in batch:
                 try:
                     out.append(runner([item])[0])
-                except Exception:
+                except ScanRejected:
                     out.append(None)
             return out
 
@@ -1338,6 +1341,14 @@ class ProgressReporter:
         print(line, file=self.stream or sys.stderr, flush=True)
 
 
+def _holds_accelerator() -> bool:
+    """Does this process hold an accelerator through JAX?  A process that
+    imported JAX resolves its default backend here (initialising it, as its
+    scan batches do anyway); any backend but the CPU counts."""
+    jax = sys.modules.get("jax")
+    return jax is not None and jax.default_backend() != "cpu"
+
+
 # ---------------------------------------------------------------------------
 def run_sweep(
     spec: SweepSpec,
@@ -1356,7 +1367,10 @@ def run_sweep(
     ``executor`` pins the pool start method: ``"fork"`` (fastest),
     ``"spawn"`` (required for XLA-using runners -- engines do not survive a
     fork, so benchmarks like ``engine_bench`` pass ``executor="spawn"`` to
-    run their cells concurrently), or ``None`` to pick automatically.
+    run their cells concurrently), or ``None`` to pick automatically.  No
+    pool starts while this process holds an accelerator through JAX (a
+    child could not initialise a backend on the held chip): the cells then
+    run in-process whatever ``workers`` and ``executor`` say.
 
     Cells on the ``"scan"`` backend are *not* sent to the pool: they are
     partitioned into padded shape buckets and dispatched as batched
@@ -1396,17 +1410,9 @@ def run_sweep(
     scan_batched = 0
     errors: dict[str, str] = {}
     if scan_pos:
-        scan_cells = [cells[i] for i in scan_pos]
-        try:
-            batch = _run_cells_scan_partial(scan_cells)
-        except Exception:
-            # batched dispatch itself fell over (not a per-cell rejection,
-            # those degrade inside): retry once, then send every scan cell
-            # through the pool path below instead of failing the sweep
-            try:
-                batch = _run_cells_scan_partial(scan_cells)
-            except Exception:
-                batch = [None] * len(scan_pos)
+        # a device or compile fault here propagates: value-dependent
+        # rejections already degrade cell by cell inside
+        batch = _run_cells_scan_partial([cells[i] for i in scan_pos])
         for i, m in zip(scan_pos, batch):
             if m is not None:
                 metrics[i] = m
@@ -1422,6 +1428,11 @@ def run_sweep(
     guarded = partial(_run_guard, fn)
     rest = [i for i in range(len(cells)) if metrics[i] is None]
     pool_workers = max(1, min(workers, len(rest)))
+    if _holds_accelerator():
+        # one process per chip: a pool child that initialised a JAX
+        # backend while this process holds the accelerator would fail or
+        # hang, so the remaining cells run here, one after another
+        pool_workers = 1
     if rest and (pool_workers == 1 or len(rest) == 1):
         for i in rest:
             metrics[i] = guarded(cells[i])

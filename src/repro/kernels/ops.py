@@ -71,19 +71,30 @@ def event_step(clk, ctr, inp, *, force: str | None = None,
     back to the oracle unless ``force="pallas"``."""
     from ..core import fastpath as _fp     # lazy: core is heavy
 
-    use_pallas = force == "pallas" or (force is None and _on_tpu())
-    if use_pallas:
+    if event_step_path(force=force, **static) == "pallas":
         from .event_step import event_step_pallas, event_step_supported
 
-        if event_step_supported(**static):
-            return event_step_pallas(clk, ctr, inp,
-                                     interpret=interpret or not _on_tpu(),
-                                     **static)
-        if force == "pallas":
+        if not event_step_supported(**static):
             raise NotImplementedError(
                 "the Pallas event_step covers only the base pull "
                 "configuration (no freeze/dyn/het/hedge/cold/dup)")
+        return event_step_pallas(clk, ctr, inp,
+                                 interpret=interpret or not _on_tpu(),
+                                 **static)
     return jax.vmap(partial(_fp._scan_cell_kernel, **static))(clk, ctr, inp)
+
+
+def event_step_path(force: str | None = None, **static) -> str:
+    """Which implementation :func:`event_step` runs for this static feature
+    set on the current backend: ``"pallas"`` (the megakernel; TPU, base
+    pull) or ``"jnp"`` (the vmapped oracle)."""
+    from .event_step import event_step_supported
+
+    if force == "ref":
+        return "jnp"
+    if force == "pallas" or (_on_tpu() and event_step_supported(**static)):
+        return "pallas"
+    return "jnp"
 
 
 def rwkv6_scan(r, k, v, w, u, *, force: str | None = None,

@@ -387,3 +387,51 @@ class TestFastpathSpeed:
                 total += time.perf_counter() - t0
             wall[backend] = total
         assert wall["reference"] / wall["vectorized"] > 4.0
+
+
+@pytest.mark.skipif(not HAVE_JAX, reason="jax not installed")
+class TestCompileCache:
+    """Placement of JAX's persistent compilation cache: an outside
+    ``JAX_COMPILATION_CACHE_DIR`` wins untouched; otherwise one fixed,
+    git-ignored directory inside the checkout, the same on every call."""
+
+    def test_env_dir_is_left_in_place(self, tmp_path):
+        import os
+        import subprocess
+        import sys
+
+        prog = (
+            "import jax\n"
+            "from repro.core import generate_burst\n"
+            "from repro.core.fastpath import simulate_cluster_scan\n"
+            "simulate_cluster_scan(generate_burst(cores=8, intensity=5,"
+            " seed=0), nodes=2, cores_per_node=4, policy='fifo')\n"
+            "print(jax.config.jax_compilation_cache_dir)\n")
+        env = {**os.environ, "JAX_PLATFORMS": "cpu",
+               "JAX_COMPILATION_CACHE_DIR": str(tmp_path)}
+        out = subprocess.run([sys.executable, "-c", prog], env=env,
+                             capture_output=True, text=True, timeout=300,
+                             check=True)
+        assert out.stdout.strip().splitlines()[-1] == str(tmp_path)
+
+    def test_fixed_in_checkout_dir_when_unset(self, monkeypatch):
+        from pathlib import Path
+
+        import jax
+        from jax.experimental.compilation_cache import compilation_cache
+
+        from repro.core import fastpath as fp
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        before = jax.config.jax_compilation_cache_dir
+        try:
+            jax.config.update("jax_compilation_cache_dir", None)
+            first = fp.ensure_compile_cache()
+            second = fp.ensure_compile_cache()
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
+            compilation_cache.reset_cache()
+        root = Path(__file__).resolve().parent.parent
+        assert first == second == str(root / ".jax_cache")
+        ignored = (root / ".gitignore").read_text().splitlines()
+        assert ".jax_cache/" in ignored

@@ -19,8 +19,9 @@ Contracts under test:
 * ``REPRO_SCAN_CHECK=1`` names the offending bucket/cell/field on a
   non-finite output and passes cleanly over healthy resilience cells;
 * ``run_sweep`` isolates faulting cells into the ``failed`` column plus
-  ``meta["errors"]``, and the batch dispatcher retries value-dependent
-  batch failures per item instead of losing the whole bucket.
+  ``meta["errors"]``, the batch dispatcher retries value-dependent
+  rejections (``ScanRejected``) per item instead of losing the whole
+  bucket, and every other scan error propagates to the caller.
 """
 
 import copy
@@ -474,14 +475,14 @@ class TestSweepFaultIsolation:
 
     @needs_jax
     def test_batch_fault_falls_back_to_per_item_dispatch(self, monkeypatch):
-        # a value-dependent mid-batch rejection must degrade to per-item
-        # dispatch, not lose the whole bucket
+        # a value-dependent mid-batch rejection (the named ScanRejected)
+        # must degrade to per-item dispatch, not lose the whole bucket
         import repro.core.fastpath as fastpath
         real = fastpath.simulate_cluster_cells_scan
 
         def poisoned(items, **kw):
             if len(items) > 1:
-                raise RuntimeError("injected batch fault")
+                raise fastpath.ScanRejected("injected batch rejection")
             return real(items, **kw)
 
         monkeypatch.setattr(
@@ -496,6 +497,42 @@ class TestSweepFaultIsolation:
         for m in metrics:
             assert math.isfinite(m["R_avg"])
             assert m["retries_issued"] >= 0
+
+    @staticmethod
+    def _fault_batched_dispatch(monkeypatch):
+        """Fail the sweep's batched scan dispatch (which skips re-validation)
+        with a device-style error; single-cell calls still work, so a
+        swallowed fault would show up as a quietly degraded row."""
+        import repro.core.fastpath as fastpath
+        real = fastpath.simulate_cluster_cells_scan
+
+        def faulty(items, validate=True, **kw):
+            if not validate:
+                raise RuntimeError("injected device fault")
+            return real(items, validate=validate, **kw)
+
+        monkeypatch.setattr(fastpath, "simulate_cluster_cells_scan", faulty)
+
+    @needs_jax
+    @pytest.mark.parametrize("strict", [False, True])
+    def test_device_fault_propagates_from_run_cells_scan(self, monkeypatch,
+                                                          strict):
+        # anything but ScanRejected (a compile refusal, a device fault) is
+        # never turned into a reference run: it reaches the caller with its
+        # own type and message, in strict and non-strict mode alike
+        self._fault_batched_dispatch(monkeypatch)
+        cells = [SweepCell(policy="sept", nodes=2, cores=4, intensity=10,
+                           backend="scan", seed=s) for s in (0, 1)]
+        with pytest.raises(RuntimeError, match="injected device fault"):
+            run_cells_scan(cells, strict=strict)
+
+    @needs_jax
+    def test_device_fault_propagates_from_run_sweep(self, monkeypatch):
+        self._fault_batched_dispatch(monkeypatch)
+        spec = SweepSpec(policies=("fifo", "sept"), nodes=(2,), cores=(4,),
+                         intensities=(10,), seeds=1, backends=("scan",))
+        with pytest.raises(RuntimeError, match="injected device fault"):
+            run_sweep(spec, workers=1)
 
     @needs_jax
     def test_strict_false_degrades_ineligible_cells(self):

@@ -109,6 +109,46 @@ class TestParallelRunner:
         assert m["n"] > 0                 # pull model recovered the rest
 
 
+class TestOneProcessPerChip:
+    """A parent that holds an accelerator through JAX starts no pool: a
+    child initialising a JAX backend on the held chip would fail or hang.
+    The test steers the parent's backend answer; nothing here needs a
+    chip."""
+
+    SPEC = SweepSpec(policies=("fifo", "sept"), intensities=(20,),
+                     cores=(5,), seeds=2)
+
+    @staticmethod
+    def _spy(monkeypatch):
+        import repro.core.sweep as sweep
+
+        started = []
+        real = sweep.ProcessPoolExecutor
+
+        def spy(*a, **kw):
+            started.append(kw.get("max_workers"))
+            return real(*a, **kw)
+
+        monkeypatch.setattr(sweep, "ProcessPoolExecutor", spy)
+        return started
+
+    def test_no_pool_while_parent_holds_accelerator(self, monkeypatch):
+        jax = pytest.importorskip("jax")
+        jax.devices()                     # the parent initialises a backend
+        started = self._spy(monkeypatch)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        res = run_sweep(self.SPEC, workers=2)
+        assert started == []
+        assert [c.metrics for c in res.results] == \
+            [c.metrics for c in run_sweep(self.SPEC, workers=1).results]
+
+    def test_cpu_parent_still_uses_the_pool(self, monkeypatch):
+        pytest.importorskip("jax").devices()
+        started = self._spy(monkeypatch)
+        run_sweep(self.SPEC, workers=2)
+        assert started == [2]
+
+
 class TestArrivalProcesses:
     RATE, DUR = 8.0, 60.0
 
