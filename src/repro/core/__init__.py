@@ -43,6 +43,7 @@ from .fastpath import (
     scan_cache_clear,
     scan_cache_stats,
     scan_eligible,
+    scan_phase_totals,
     scan_timings_clear,
     simulate_cells_scan,
     simulate_cluster_cells_scan,
@@ -220,6 +221,7 @@ __all__ = [
     "scan_bucket_timings",
     "scan_cache_clear",
     "scan_cache_stats",
+    "scan_phase_totals",
     "scan_timings_clear",
     "scan_eligible",
     "simulate_baseline_cluster",

@@ -62,7 +62,7 @@ import os
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from functools import partial
+from functools import partial, update_wrapper
 from pathlib import Path
 
 import numpy as np
@@ -1941,7 +1941,13 @@ _SCAN_CACHE_STATS = {"hits": 0, "misses": 0}
 # ``engine_bench --rows mega``; bounded so long sessions don't grow them
 _SCAN_TIMINGS: list[dict] = []
 _SCAN_TIMINGS_MAX = 4096
-_SCAN_PROFILE_DONE = False       # REPRO_SCAN_PROFILE one-shot latch
+# process-wide self time of each scan-entry phase (``_phase``): name ->
+# [seconds, count]; the stack holds the child seconds of each open phase
+_PHASE_TOTALS: dict[str, list] = {}
+_PHASE_STACK: list[float] = []
+# event-step slots dispatched (padded cells and steps included) and the
+# arrival + completion steps of the real calls in them
+_STEP_COUNTS = {"step_slots": 0, "call_steps": 0}
 
 
 def _pow2(x: int) -> int:
@@ -1986,25 +1992,78 @@ def scan_bucket_timings() -> list[dict]:
     """Per-chunk dispatch timing records (most recent last).  Each record:
     ``bucket`` tag, ``bsz`` (padded batch), ``cells`` (real cells), and
     seconds split into ``build_s`` (host input fill), ``compile_s`` (XLA
-    compile, zero on cache hits), ``dispatch_s`` (device call issue) and
-    ``sync_s`` (host block + unpack).  A bucket whose chunk size was
-    auto-tuned additionally carries one ``cells == 0`` record with the
-    probe wall in ``tune_s`` -- one-time setup cost, like compiles."""
+    compile, zero on cache hits), ``dispatch_s`` (device call issue),
+    ``wait_s`` (host blocked on the device), ``unpack_s`` (copy back,
+    budget checks and per-cell unpack) and ``sync_s`` (from the block to
+    the end of the budget checks, the per-cell unpack left out).  A bucket
+    whose chunk size was auto-tuned additionally carries one ``cells == 0``
+    record with the probe wall in ``tune_s`` and the probes' compiles in
+    ``compile_s`` -- one-time setup cost.  Every key but ``sync_s`` is
+    the self time of its :func:`scan_phase_totals` phase in that chunk."""
     return list(_SCAN_TIMINGS)
 
 
+def scan_phase_totals() -> dict:
+    """Self time and count of each scan-entry phase since the last
+    :func:`scan_timings_clear`, as ``{phase: {"s": seconds, "n": count}}``,
+    plus two step counters: ``step_slots`` (cells x event steps
+    dispatched, padding included) and ``call_steps`` (the arrival and
+    completion step of every real call in them).  Each phase is also a
+    ``fastpath.<phase>`` span in a ``jax.profiler`` trace: ``entry`` (a
+    batch entry), ``prepare`` (cell features and bucketing), ``tune``,
+    ``compile``, ``fill``, ``dispatch``, ``wait`` and ``unpack``.  A
+    phase's self time leaves out the phases nested in it."""
+    out: dict = {name: {"s": s, "n": n}
+                 for name, (s, n) in _PHASE_TOTALS.items()}
+    out.update(_STEP_COUNTS)
+    return out
+
+
 def scan_timings_clear() -> None:
-    """Reset the timing log *and* the REPRO_SCAN_PROFILE one-shot latch, so
-    a later sweep in the same process can dump a fresh profiler trace."""
-    global _SCAN_PROFILE_DONE
+    """Reset the timing log, the phase totals and the step counters."""
     _SCAN_TIMINGS.clear()
-    _SCAN_PROFILE_DONE = False
+    _PHASE_TOTALS.clear()
+    for k in _STEP_COUNTS:
+        _STEP_COUNTS[k] = 0
 
 
 def _record_timing(rec: dict) -> None:
     if len(_SCAN_TIMINGS) >= _SCAN_TIMINGS_MAX:
         del _SCAN_TIMINGS[:_SCAN_TIMINGS_MAX // 2]
     _SCAN_TIMINGS.append(rec)
+
+
+def _timing_record(tag: str, bsz: int, cells: int) -> dict:
+    return {"bucket": tag, "bsz": bsz, "cells": cells, "build_s": 0.0,
+            "compile_s": 0.0, "dispatch_s": 0.0, "wait_s": 0.0,
+            "unpack_s": 0.0, "sync_s": 0.0}
+
+
+@contextlib.contextmanager
+def _phase(name: str, rec: dict | None = None, key: str | None = None,
+           **args):
+    """One scan-entry phase: a ``fastpath.<name>`` profiler span (with
+    ``args`` as its annotation arguments) whose self time is added to the
+    process-wide totals and, where the phase belongs to a chunk, to that
+    chunk's timing record under ``key``.  Wrap whole loops, never one
+    iteration of a per-cell loop."""
+    from jax.profiler import TraceAnnotation
+
+    with TraceAnnotation("fastpath." + name, **args):
+        _PHASE_STACK.append(0.0)
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            dt = time.perf_counter() - t0
+            own = dt - _PHASE_STACK.pop()
+            if _PHASE_STACK:
+                _PHASE_STACK[-1] += dt
+            tot = _PHASE_TOTALS.setdefault(name, [0.0, 0])
+            tot[0] += own
+            tot[1] += 1
+            if rec is not None:
+                rec[key] += own
 
 
 # The carry of ``_scan_cell_kernel`` is an ordered pipeline of feature-flagged
@@ -2199,8 +2258,11 @@ def _runner_fns(shape_key: tuple):
 
     state_kw, step_kw = _runner_kwargs(shape_key)
     init_fn = jax.jit(jax.vmap(partial(_make_planes, **state_kw)))
-    scan_fn = jax.jit(partial(_kops.event_step, **step_kw),
-                      donate_argnums=(0, 1))
+    # named after the kernel, so the step's XLA module is
+    # ``jit_event_step`` and not a bare partial's ``jit__unknown``
+    step = update_wrapper(partial(_kops.event_step, **step_kw),
+                          _kops.event_step)
+    scan_fn = jax.jit(step, donate_argnums=(0, 1))
     return init_fn, scan_fn
 
 
@@ -2264,13 +2326,14 @@ def _evict_runners(current: tuple) -> None:
         entry.compile_s.pop(bsz, None)
 
 
-def _scan_runner(key: tuple):
+def _scan_runner(key: tuple, rec: dict | None = None):
     """AOT-compiled ``(init, scan)`` pair for one bucket shape at one chunk
     batch size: ``key = (feature_mask, n_req, n_nodes, n_slots, n_fns,
     fn_queue_cap, window, fc_ring, n_ep, n_copies, xtra, batch)`` -- the
     leading element is the :func:`_feature_mask` bitmask of enabled carry
     segments, the trailing one the padded chunk batch.  All batch sizes of
-    one shape share a single LRU cache entry (see :class:`_CacheEntry`)."""
+    one shape share a single LRU cache entry (see :class:`_CacheEntry`).
+    A miss compiles inside a ``compile`` phase, timed into ``rec``."""
     shape_key, bsz = key[:-1], key[-1]
     entry = _cache_entry(shape_key)
     pair = entry.runners.pop(bsz, None)
@@ -2280,7 +2343,8 @@ def _scan_runner(key: tuple):
         _SCAN_CACHE_STATS["hits"] += 1
         return pair
     _SCAN_CACHE_STATS["misses"] += 1
-    pair, secs = _build_runner(shape_key, bsz)
+    with _phase("compile", rec, "compile_s"):
+        pair, secs = _build_runner(shape_key, bsz)
     entry.runners[bsz] = pair
     entry.compile_s[bsz] = secs
     _evict_runners(shape_key)
@@ -2299,22 +2363,26 @@ def _bucket_bytes(shape_key: tuple, bsz: int) -> int:
     return (per_cell * 3 + outs) * bsz
 
 
-def _bucket_chunk(shape_key: tuple, n_cells: int) -> int:
+def _bucket_chunk(shape_key: tuple, n_cells: int,
+                  rec: dict | None = None) -> int:
     """Cells per dispatched chunk for this bucket: the auto-tuned value when
     one exists, else :data:`SCAN_BATCH_MAX`.  Tuning runs once per (shape,
     backend) the first time the bucket arrives with more cells than the
-    default chunk, and the choice persists on the cache entry (visible in
+    default chunk, inside a ``tune`` phase timed into ``rec``, and the
+    choice persists on the cache entry (visible in
     ``scan_cache_stats()["entries"]``)."""
     entry = _cache_entry(shape_key)
     if entry.chunk is not None:
         return entry.chunk
     if not SCAN_AUTOTUNE or n_cells <= SCAN_BATCH_MAX:
         return SCAN_BATCH_MAX
-    entry.chunk = _autotune_chunk(shape_key, n_cells)
+    with _phase("tune", rec, "tune_s"):
+        entry.chunk = _autotune_chunk(shape_key, n_cells, rec)
     return entry.chunk
 
 
-def _autotune_chunk(shape_key: tuple, n_cells: int) -> int:
+def _autotune_chunk(shape_key: tuple, n_cells: int,
+                    rec: dict | None = None) -> int:
     """One-time chunk-size measurement for one bucket shape: time the idle
     bucket (per-step cost is value-independent) at power-of-two batch sizes
     under the :data:`SCAN_MEM_MB` footprint cap and keep the cells/sec
@@ -2333,7 +2401,7 @@ def _autotune_chunk(shape_key: tuple, n_cells: int) -> int:
         return min(SCAN_BATCH_MAX, cap)
 
     def _rate(bsz: int) -> float:
-        init_c, scan_c = _scan_runner(shape_key + (bsz,))
+        init_c, scan_c = _scan_runner(shape_key + (bsz,), rec)
         inp = _alloc_bucket_inputs(shape_key, bsz)
         best = np.inf
         for _ in range(3):       # min-of-3: robust to scheduler noise
@@ -2644,8 +2712,6 @@ def _run_scan_bucket(key: tuple, cells: list[_ScanCell]) -> list[tuple]:
     import jax
     import jax.numpy as jnp
 
-    global _SCAN_PROFILE_DONE
-
     (mask, n_b, nodes_b, slots_b, f_b, kq, window, fc_ring, n_ep, n_copies,
      xtra) = key
     flags = _mask_features(mask)
@@ -2655,47 +2721,56 @@ def _run_scan_bucket(key: tuple, cells: list[_ScanCell]) -> list[tuple]:
     n1 = n_b + 1
     use64 = _use64(flags)
     tag = _bucket_tag(key)
-    t_tune = time.perf_counter()
-    chunk_max = _bucket_chunk(key, len(cells))
-    t_tune = time.perf_counter() - t_tune
-    if t_tune > 0.005:
-        # the auto-tuner probed this shape (compiles + timed runs): surface
-        # the one-time cost as its own record so rate analyses can separate
-        # it from steady-state dispatch, like compile time
-        _record_timing({"bucket": tag, "bsz": 0, "cells": 0, "build_s": 0.0,
-                        "compile_s": 0.0, "dispatch_s": 0.0, "sync_s": 0.0,
-                        "tune_s": t_tune})
+    # the auto-tuner's probes (compiles + timed runs) are a one-time cost
+    # with a record of their own, apart from steady-state dispatch
+    tune = dict(_timing_record(tag, 0, 0), tune_s=0.0)
+    chunk_max = _bucket_chunk(key, len(cells), tune)
+    if tune["tune_s"]:
+        _record_timing(tune)
     out: list[tuple | None] = [None] * len(cells)
     pending: deque = deque()
 
-    def _dispatch(inp, xtra_now: int, rec: dict):
+    def _dispatch(inp, xtra_now: int, rec: dict, chunk: list):
         """Issue one chunk on the device and return the *un-synced* result
         tree (JAX dispatch is asynchronous, so this returns as soon as the
         work is enqueued)."""
         bsz = inp["cores"].shape[0]
-        t0 = time.perf_counter()
         init_c, scan_c = _scan_runner((mask, n_b, nodes_b, slots_b, f_b,
                                        kq, window, fc_ring, n_ep, n_copies,
-                                       xtra_now, bsz))
-        rec["compile_s"] += time.perf_counter() - t0
-        t0 = time.perf_counter()
-        with _x64_ctx(use64):
+                                       xtra_now, bsz), rec)
+        _STEP_COUNTS["step_slots"] += bsz * (2 * n_b + xtra_now)
+        _STEP_COUNTS["call_steps"] += 2 * sum(len(c.feats.t) for c in chunk)
+        with _phase("dispatch", rec, "dispatch_s"), _x64_ctx(use64):
             # float64 buckets convert inputs *inside* enable_x64 --
             # quantizing kill/arrival/deadline times through float32 first
             # would merge distinct event times and reintroduce exactly the
             # ordering flips the promotion prevents
             arrs = {k: jnp.asarray(v) for k, v in inp.items()}
             clk, ctr = init_c(arrs)
-            res = scan_c(clk, ctr, arrs)
-        rec["dispatch_s"] += time.perf_counter() - t0
-        return res
+            return scan_c(clk, ctr, arrs)
+
+    def _collect(res, rec: dict):
+        """Block on one dispatched chunk, then copy its results back."""
+        with _phase("wait", rec, "wait_s"):
+            jax.block_until_ready(res)
+        return jax.tree_util.tree_map(np.asarray, res)
 
     def _finish(lo: int, chunk: list, inp: dict, res, rec: dict) -> None:
         """Host-sync one in-flight chunk, verify hedge step budgets
         (re-running at the strict bound when the optimistic guess fell
         short) and unpack per-cell outputs into ``out``."""
         t0 = time.perf_counter()
-        res = jax.tree_util.tree_map(np.asarray, res)    # blocks
+        with _phase("wait", rec, "wait_s"):
+            jax.block_until_ready(res)
+        with _phase("unpack", rec, "unpack_s"):
+            res = _sync(chunk, inp, res, rec)
+            rec["sync_s"] += time.perf_counter() - t0
+            _unpack(lo, chunk, inp, res)
+        _record_timing(rec)
+
+    def _sync(chunk: list, inp: dict, res, rec: dict):
+        """Copy one chunk's results back and check its step budgets."""
+        res = jax.tree_util.tree_map(np.asarray, res)
         if hedge:
             ndone_b = (res[1] if dyn else res[4])["ndone"]
             if any(int(ndone_b[b]) != len(chunk[b].feats.t)
@@ -2706,8 +2781,7 @@ def _run_scan_bucket(key: tuple, cells: list[_ScanCell]) -> list[tuple]:
                 # construction
                 full = max(c.dyn_budget() + c.hedge_budget_full()
                            for c in chunk)
-                res = jax.tree_util.tree_map(
-                    np.asarray, _dispatch(inp, _pow2(full), rec))
+                res = _collect(_dispatch(inp, _pow2(full), rec, chunk), rec)
                 ndone_b = (res[1] if dyn else res[4])["ndone"]
                 for b, cell in enumerate(chunk):
                     if int(ndone_b[b]) != len(cell.feats.t):
@@ -2726,10 +2800,11 @@ def _run_scan_bucket(key: tuple, cells: list[_ScanCell]) -> list[tuple]:
                 # only fires on a genuine kernel budget bug
                 full = max(c.dyn_budget() + c.hedge_budget()
                            + c.res_budget_full() for c in chunk)
-                res = jax.tree_util.tree_map(
-                    np.asarray, _dispatch(inp, _pow2(full), rec))
-        rec["sync_s"] += time.perf_counter() - t0
-        _record_timing(rec)
+                res = _collect(_dispatch(inp, _pow2(full), rec, chunk), rec)
+        return res
+
+    def _unpack(lo: int, chunk: list, inp: dict, res) -> None:
+        """Per-cell outputs of one synced chunk into ``out``."""
         if not dyn and not resil:
             start_b, finish_b, prio_b, node_b, aux = res
             for b in range(len(chunk)):
@@ -2815,23 +2890,10 @@ def _run_scan_bucket(key: tuple, cells: list[_ScanCell]) -> list[tuple]:
     for lo in range(0, len(cells), chunk_max):
         chunk = cells[lo:lo + chunk_max]
         bsz = _pow2(len(chunk))
-        t_build = time.perf_counter()
-        inp = _fill_bucket_inputs(key, chunk, bsz)
-        rec = {"bucket": tag, "bsz": bsz, "cells": len(chunk),
-               "build_s": time.perf_counter() - t_build,
-               "compile_s": 0.0, "dispatch_s": 0.0, "sync_s": 0.0}
-        if (os.environ.get("REPRO_SCAN_PROFILE") == "1"
-                and not _SCAN_PROFILE_DONE):
-            # one-shot REPRO_SCAN_PROFILE=1 hook: dump a jax.profiler trace
-            # of a single bucket dispatch (view with TensorBoard / xprof)
-            _SCAN_PROFILE_DONE = True
-            tdir = os.environ.get("REPRO_SCAN_PROFILE_DIR",
-                                  "/tmp/repro_scan_profile")
-            with jax.profiler.trace(tdir):
-                res = _dispatch(inp, xtra, rec)
-                jax.block_until_ready(res)
-        else:
-            res = _dispatch(inp, xtra, rec)
+        rec = _timing_record(tag, bsz, len(chunk))
+        with _phase("fill", rec, "build_s"):
+            inp = _fill_bucket_inputs(key, chunk, bsz)
+        res = _dispatch(inp, xtra, rec, chunk)
         pending.append((lo, chunk, inp, res, rec))
         # bounded async window: every chunk is dispatched before its
         # predecessors are synced, so device work overlaps the host-side
@@ -2907,16 +2969,21 @@ def _run_scan_cells(cells: list[_ScanCell],
     returns :class:`ScanMetrics` rows instead of :class:`SimResult` -- the
     interactive-sweep mode, where cells share workloads and only aggregate
     metrics leave the batch."""
-    buckets: dict[tuple, list[int]] = {}
-    for i, cell in enumerate(cells):
-        buckets.setdefault(cell.bucket(), []).append(i)
+    with _phase("prepare"):
+        buckets: dict[tuple, list[int]] = {}
+        for i, cell in enumerate(cells):
+            buckets.setdefault(cell.bucket(), []).append(i)
     results: list = [None] * len(cells)
     req_cache: dict = {}
     for key, idxs in buckets.items():
         arrays = _run_scan_bucket(key, [cells[i] for i in idxs])
-        for i, (start, finish, prio, node, extras) in zip(idxs, arrays):
-            cell = cells[i]
-            if metrics_only:
+        with _phase("unpack"):
+            for i, (start, finish, prio, node, extras) in zip(idxs, arrays):
+                cell = cells[i]
+                if not metrics_only:
+                    results[i] = _write_back(cell, start, finish, prio, node,
+                                             extras)
+                    continue
                 if cell.res:
                     # resilience cells can terminate requests without a
                     # completion; the metrics-only fold assumes every
@@ -2926,79 +2993,79 @@ def _run_scan_cells(cells: list[_ScanCell],
                         "cells; run them through the write-back path")
                 results[i] = _cell_scan_metrics(cell, finish, extras,
                                                 req_cache)
-                continue
-            f = cell.feats
-            order = f.order.tolist()
-            t_list = f.t.tolist()
-            att = extras.get("attempts") if extras is not None else None
-            coldq = extras.get("coldq") if extras is not None else None
-            fmask = (extras.get("failed_mask")
-                     if extras is not None else None)
-            fcause = extras.get("failed_cause") if extras is not None else None
-            ratt = extras.get("attempts_res") if extras is not None else None
-            for e, ridx in enumerate(order):
-                req = cell.requests[ridx]
-                req.node = f"node{int(node[e])}"
-                req.r_prime = t_list[e]
-                req.priority = float(prio[e])    # float32-rounded
-                # warm cells never cold-start; cold cells carry the
-                # original's own dispatch decision per request
-                req.cold_start = bool(coldq[e]) if coldq is not None else False
-                if fmask is not None and bool(fmask[e]):
-                    # terminal failure: the recorded start/finish belong to
-                    # a cancelled attempt -- the client never saw a response
-                    req.start = req.finish = req.c = None
-                    req.failed = "timeout" if int(fcause[e]) == 1 else "shed"
-                    req.attempts = max(int(ratt[e]) - 1, 0)
-                    continue
-                req.start = float(start[e])
-                req.finish = float(finish[e])
-                req.c = req.finish + RESP_OVERHEAD_S
-                req.failed = None
-                if att is not None:              # hedged cell: backup count
-                    req.attempts = int(att[e])
-                if ratt is not None:             # resubmission count
-                    req.attempts = max(int(ratt[e]) - 1, 0)
-            meta = {"mode": "ours", "policy": cell.policy,
-                    "cores": cell.cores, "backend": "scan"}
-            if cell.assignment != "single":
-                meta["nodes"] = cell.nodes
-                meta["assignment"] = cell.assignment
-            failures = backups = steals = 0
-            cold_starts = evictions = 0
-            timed_out = shed = retries_issued = 0
-            wasted_work = 0.0
-            nodes_used = cell.nodes
-            timeline = None
-            if extras is not None:
-                failures = extras.get("failures", 0)
-                backups = extras.get("backups", 0)
-                steals = extras.get("steals", 0)
-                cold_starts = extras.get("cold_starts", 0)
-                evictions = extras.get("evictions", 0)
-                timed_out = extras.get("timed_out", 0)
-                shed = extras.get("shed", 0)
-                retries_issued = extras.get("retries_issued", 0)
-                wasted_work = extras.get("wasted_work", 0.0)
-                if "act_t" in extras:        # dynamic-capacity cell
-                    from .cluster import CapacityTimeline
-                    nodes_used = extras["nodes_used"]
-                    timeline = CapacityTimeline(
-                        activate=[float(a)
-                                  for a in extras["act_t"][:nodes_used]],
-                        deactivate=[float(extras["killt"][k])
-                                    if bool(extras["dead"][k])
-                                    else float("inf")
-                                    for k in range(nodes_used)])
-            results[i] = SimResult(
-                requests=cell.requests, cold_starts=cold_starts,
-                evictions=evictions, creations=0, failures=failures,
-                backups_issued=backups, steals_won=steals,
-                nodes_used=nodes_used, timeline=timeline,
-                timed_out=timed_out, shed=shed,
-                retries_issued=retries_issued, wasted_work=wasted_work,
-                meta=meta)
     return results  # type: ignore[return-value]
+
+
+def _write_back(cell: _ScanCell, start, finish, prio, node,
+                extras) -> SimResult:
+    """Write one cell's event-order outputs back into its requests and
+    wrap them in a :class:`SimResult`."""
+    f = cell.feats
+    order = f.order.tolist()
+    t_list = f.t.tolist()
+    att = extras.get("attempts") if extras is not None else None
+    coldq = extras.get("coldq") if extras is not None else None
+    fmask = extras.get("failed_mask") if extras is not None else None
+    fcause = extras.get("failed_cause") if extras is not None else None
+    ratt = extras.get("attempts_res") if extras is not None else None
+    for e, ridx in enumerate(order):
+        req = cell.requests[ridx]
+        req.node = f"node{int(node[e])}"
+        req.r_prime = t_list[e]
+        req.priority = float(prio[e])    # float32-rounded
+        # warm cells never cold-start; cold cells carry the
+        # original's own dispatch decision per request
+        req.cold_start = bool(coldq[e]) if coldq is not None else False
+        if fmask is not None and bool(fmask[e]):
+            # terminal failure: the recorded start/finish belong to
+            # a cancelled attempt -- the client never saw a response
+            req.start = req.finish = req.c = None
+            req.failed = "timeout" if int(fcause[e]) == 1 else "shed"
+            req.attempts = max(int(ratt[e]) - 1, 0)
+            continue
+        req.start = float(start[e])
+        req.finish = float(finish[e])
+        req.c = req.finish + RESP_OVERHEAD_S
+        req.failed = None
+        if att is not None:              # hedged cell: backup count
+            req.attempts = int(att[e])
+        if ratt is not None:             # resubmission count
+            req.attempts = max(int(ratt[e]) - 1, 0)
+    meta = {"mode": "ours", "policy": cell.policy,
+            "cores": cell.cores, "backend": "scan"}
+    if cell.assignment != "single":
+        meta["nodes"] = cell.nodes
+        meta["assignment"] = cell.assignment
+    failures = backups = steals = 0
+    cold_starts = evictions = 0
+    timed_out = shed = retries_issued = 0
+    wasted_work = 0.0
+    nodes_used = cell.nodes
+    timeline = None
+    if extras is not None:
+        failures = extras.get("failures", 0)
+        backups = extras.get("backups", 0)
+        steals = extras.get("steals", 0)
+        cold_starts = extras.get("cold_starts", 0)
+        evictions = extras.get("evictions", 0)
+        timed_out = extras.get("timed_out", 0)
+        shed = extras.get("shed", 0)
+        retries_issued = extras.get("retries_issued", 0)
+        wasted_work = extras.get("wasted_work", 0.0)
+        if "act_t" in extras:        # dynamic-capacity cell
+            from .cluster import CapacityTimeline
+            nodes_used = extras["nodes_used"]
+            timeline = CapacityTimeline(
+                activate=[float(a) for a in extras["act_t"][:nodes_used]],
+                deactivate=[float(extras["killt"][k])
+                            if bool(extras["dead"][k]) else float("inf")
+                            for k in range(nodes_used)])
+    return SimResult(
+        requests=cell.requests, cold_starts=cold_starts,
+        evictions=evictions, creations=0, failures=failures,
+        backups_issued=backups, steals_won=steals, nodes_used=nodes_used,
+        timeline=timeline, timed_out=timed_out, shed=shed,
+        retries_issued=retries_issued, wasted_work=wasted_work, meta=meta)
 
 
 def _feats_cache():
@@ -3041,23 +3108,31 @@ def simulate_cells_scan(
     shared across many cells)."""
     if not batch:
         return []
-    feats = _feats_cache()
-    cells = []
-    for item in batch:
-        requests, cores, policy = item[:3]
-        warm = item[3] if len(item) > 3 else True
-        if validate and not scan_eligible(requests, cores, policy,
-                                          warm=warm, memory_mb=memory_mb,
-                                          container_mb=container_mb):
-            raise ScanRejected(
-                "scan backend requires the ours regime, a known policy and "
-                "(cold cells) ample container memory "
-                f"(policy={policy!r}, cores={cores}, warm={warm}); use "
-                "backend='vectorized' for the general exact fast path")
-        cells.append(_ScanCell(requests=requests, feats=feats(requests),
-                               cores=cores, nodes=1, policy=policy,
-                               assignment="single", warm=warm))
-    return _run_scan_cells(cells, metrics_only=metrics_only)
+    with _entry_phase(batch):
+        feats = _feats_cache()
+        with _phase("prepare"):
+            cells = [_single_cell(item, feats, memory_mb, container_mb,
+                                  validate) for item in batch]
+        return _run_scan_cells(cells, metrics_only=metrics_only)
+
+
+def _single_cell(item: tuple, feats, memory_mb: int, container_mb: int,
+                 validate: bool) -> _ScanCell:
+    """One :func:`simulate_cells_scan` batch item as a prepared cell,
+    checked against :func:`scan_eligible` when ``validate``."""
+    requests, cores, policy = item[:3]
+    warm = item[3] if len(item) > 3 else True
+    if validate and not scan_eligible(requests, cores, policy,
+                                      warm=warm, memory_mb=memory_mb,
+                                      container_mb=container_mb):
+        raise ScanRejected(
+            "scan backend requires the ours regime, a known policy and "
+            "(cold cells) ample container memory "
+            f"(policy={policy!r}, cores={cores}, warm={warm}); use "
+            "backend='vectorized' for the general exact fast path")
+    return _ScanCell(requests=requests, feats=feats(requests),
+                     cores=cores, nodes=1, policy=policy,
+                     assignment="single", warm=warm)
 
 
 # ---------------------------------------------------------------------------
@@ -3187,36 +3262,55 @@ def simulate_cluster_cells_scan(
     """
     if not batch:
         return []
-    feats = _feats_cache()
-    cells = []
-    for item in batch:
-        requests, nodes, cores, policy = item[:4]
-        assignment = item[4] if len(item) > 4 else "pull"
-        lb = item[5] if len(item) > 5 else "least_loaded"
-        dynamics = item[6] if len(item) > 6 else None
-        profile = item[7] if len(item) > 7 else None
-        hedging = item[8] if len(item) > 8 else None
-        warm = item[9] if len(item) > 9 else True
-        resilience = item[10] if len(item) > 10 else None
-        if validate and not cluster_scan_eligible(
-                requests, nodes, cores, policy, assignment=assignment,
-                lb=lb, warm=warm, memory_mb=memory_mb,
-                container_mb=container_mb, dynamics=dynamics,
-                profile=profile, hedging=hedging, resilience=resilience):
-            raise ScanRejected(
-                "scan cluster backend requires the ours regime with "
-                "supported dynamics/heterogeneity/hedging/resilience and, "
-                "for cold cells, ample container memory "
-                f"(policy={policy!r}, nodes={nodes}, cores={cores}, "
-                f"assignment={assignment!r}, warm={warm}, "
-                f"dynamics={dynamics!r}, hedging={hedging!r}, "
-                f"resilience={resilience!r}); use backend='reference'")
-        cells.append(_ScanCell(requests=requests, feats=feats(requests),
-                               cores=cores, nodes=nodes, policy=policy,
-                               assignment=assignment, lb=lb, warm=warm,
-                               dynamics=dynamics, profile=profile,
-                               hedging=hedging, resilience=resilience))
-    return _run_scan_cells(cells, metrics_only=metrics_only)
+    with _entry_phase(batch):
+        feats = _feats_cache()
+        with _phase("prepare"):
+            cells = [_cluster_cell(item, feats, memory_mb, container_mb,
+                                   validate) for item in batch]
+        return _run_scan_cells(cells, metrics_only=metrics_only)
+
+
+def _entry_phase(batch: list[tuple]):
+    """The ``entry`` phase of one batch; while a profiler trace is being
+    taken, its span carries the batch's cell and call counts."""
+    from jax.profiler import TraceAnnotation
+
+    if not TraceAnnotation.is_enabled():
+        return _phase("entry")
+    return _phase("entry", cells=len(batch),
+                  calls=sum(len(item[0]) for item in batch))
+
+
+def _cluster_cell(item: tuple, feats, memory_mb: int, container_mb: int,
+                  validate: bool) -> _ScanCell:
+    """One :func:`simulate_cluster_cells_scan` batch item as a prepared
+    cell, checked against :func:`cluster_scan_eligible` when ``validate``."""
+    requests, nodes, cores, policy = item[:4]
+    assignment = item[4] if len(item) > 4 else "pull"
+    lb = item[5] if len(item) > 5 else "least_loaded"
+    dynamics = item[6] if len(item) > 6 else None
+    profile = item[7] if len(item) > 7 else None
+    hedging = item[8] if len(item) > 8 else None
+    warm = item[9] if len(item) > 9 else True
+    resilience = item[10] if len(item) > 10 else None
+    if validate and not cluster_scan_eligible(
+            requests, nodes, cores, policy, assignment=assignment,
+            lb=lb, warm=warm, memory_mb=memory_mb,
+            container_mb=container_mb, dynamics=dynamics,
+            profile=profile, hedging=hedging, resilience=resilience):
+        raise ScanRejected(
+            "scan cluster backend requires the ours regime with "
+            "supported dynamics/heterogeneity/hedging/resilience and, "
+            "for cold cells, ample container memory "
+            f"(policy={policy!r}, nodes={nodes}, cores={cores}, "
+            f"assignment={assignment!r}, warm={warm}, "
+            f"dynamics={dynamics!r}, hedging={hedging!r}, "
+            f"resilience={resilience!r}); use backend='reference'")
+    return _ScanCell(requests=requests, feats=feats(requests),
+                     cores=cores, nodes=nodes, policy=policy,
+                     assignment=assignment, lb=lb, warm=warm,
+                     dynamics=dynamics, profile=profile,
+                     hedging=hedging, resilience=resilience)
 
 
 def simulate_cluster_scan(

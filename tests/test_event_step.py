@@ -16,8 +16,8 @@ Contracts under test:
   are memoized no-ops -- the determinism contract;
 * degraded (ineligible) cells under ``strict=False`` do not churn the
   compile cache: batch-size variation folds into one entry per shape;
-* ``scan_bucket_timings()`` records every dispatched chunk and
-  ``REPRO_SCAN_PROFILE=1`` dumps one jax.profiler trace.
+* ``scan_bucket_timings()`` records every dispatched chunk, and a
+  jax.profiler trace around a sweep holds the ``fastpath.*`` spans.
 """
 
 import sys
@@ -221,9 +221,9 @@ class TestAutotune:
         calls = []
         real = fp._autotune_chunk
 
-        def counting(key, n_cells):
+        def counting(key, n_cells, rec=None):
             calls.append(key)
-            return real(key, n_cells)
+            return real(key, n_cells, rec)
 
         monkeypatch.setattr(fp, "_autotune_chunk", counting)
         c1 = fp._bucket_chunk(self.KEY, 130)
@@ -329,12 +329,15 @@ class TestObservability:
         assert a["total_s"] == pytest.approx(1.6)
         assert a["cells_per_s"] == pytest.approx(10 / 1.6)
 
-    def test_profile_trace_dump(self, monkeypatch, tmp_path):
-        from repro.core import fastpath as fp
+    def test_profile_trace_dump(self, tmp_path):
+        # a jax.profiler trace taken around a sweep holds the scan entry's
+        # own spans on its host planes
+        from jax.profiler import ProfileData
 
-        monkeypatch.setattr(fp, "_SCAN_PROFILE_DONE", False)
-        monkeypatch.setenv("REPRO_SCAN_PROFILE", "1")
-        monkeypatch.setenv("REPRO_SCAN_PROFILE_DIR", str(tmp_path))
-        run_cells_scan(self.CELLS, metrics_only=True)
-        assert fp._SCAN_PROFILE_DONE
-        assert any(tmp_path.rglob("*"))      # one trace, dumped once
+        with jax.profiler.trace(str(tmp_path)):
+            run_cells_scan(self.CELLS, metrics_only=True)
+        (path,) = tmp_path.rglob("*.xplane.pb")     # one trace, dumped once
+        names = {ev.name for plane in ProfileData.from_file(str(path)).planes
+                 for line in plane.lines for ev in line.events}
+        assert {"fastpath.entry", "fastpath.fill", "fastpath.dispatch",
+                "fastpath.wait", "fastpath.unpack"} <= names

@@ -18,8 +18,8 @@ Contracts under test:
   the Chrome-trace export is loadable JSON with one lane per busy slot,
   ``explain`` renders a lifecycle narrative, manifests capture provenance;
 * ``run_sweep(progress=...)`` fires the callback and ``ProgressReporter``
-  rate-limits correctly; ``scan_timings_clear`` resets the one-shot
-  profile latch (regression);
+  rate-limits correctly; ``scan_timings_clear`` resets the chunk records
+  and the phase totals;
 * tracing is opt-in: ``trace=False`` attaches nothing and installs no
   recorder in the engines.
 """
@@ -382,14 +382,18 @@ class TestProgress:
 
 
 def test_scan_timings_clear_resets_profile_latch():
-    # regression: the one-shot REPRO_SCAN_PROFILE summary latch used to
-    # survive scan_timings_clear(), so a second profiled run stayed silent
+    # scan_timings_clear() resets the chunk records, the phase totals and
+    # the step counters together, so a later window reads only its own
     from repro.core import fastpath
-    fastpath._SCAN_PROFILE_DONE = True
+    with fastpath._phase("fill", {"build_s": 0.0}, "build_s"):
+        pass
     fastpath._SCAN_TIMINGS.append({"cells": 1})
+    fastpath._STEP_COUNTS["step_slots"] += 8
+    assert fastpath.scan_phase_totals()["fill"]["n"] == 1
     fastpath.scan_timings_clear()
-    assert fastpath._SCAN_PROFILE_DONE is False
     assert fastpath.scan_bucket_timings() == []
+    assert fastpath.scan_phase_totals() == {"step_slots": 0,
+                                            "call_steps": 0}
 
 
 # ---------------------------------------------------------------------------
