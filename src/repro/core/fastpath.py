@@ -1945,9 +1945,10 @@ _SCAN_TIMINGS_MAX = 4096
 # [seconds, count]; the stack holds the child seconds of each open phase
 _PHASE_TOTALS: dict[str, list] = {}
 _PHASE_STACK: list[float] = []
-# event-step slots dispatched (padded cells and steps included) and the
-# arrival + completion steps of the real calls in them
-_STEP_COUNTS = {"step_slots": 0, "call_steps": 0}
+# event-step slots dispatched (padded cells and steps included), the
+# arrival + completion steps of the real calls in them, and the steps the
+# step actually runs (see ``_exec_steps``)
+_STEP_COUNTS = {"step_slots": 0, "call_steps": 0, "exec_steps": 0}
 
 
 def _pow2(x: int) -> int:
@@ -2006,9 +2007,10 @@ def scan_bucket_timings() -> list[dict]:
 def scan_phase_totals() -> dict:
     """Self time and count of each scan-entry phase since the last
     :func:`scan_timings_clear`, as ``{phase: {"s": seconds, "n": count}}``,
-    plus two step counters: ``step_slots`` (cells x event steps
-    dispatched, padding included) and ``call_steps`` (the arrival and
-    completion step of every real call in them).  Each phase is also a
+    plus three step counters: ``step_slots`` (cells x event steps
+    dispatched, padding included), ``call_steps`` (the arrival and
+    completion step of every real call in them) and ``exec_steps`` (the
+    event steps the step runs, :func:`_exec_steps`).  Each phase is also a
     ``fastpath.<phase>`` span in a ``jax.profiler`` trace: ``entry`` (a
     batch entry), ``prepare`` (cell features and bucketing), ``tune``,
     ``compile``, ``fill``, ``dispatch``, ``wait`` and ``unpack``.  A
@@ -2155,10 +2157,10 @@ def ensure_compile_cache() -> str | None:
 def _alloc_bucket_inputs(shape_key: tuple, bsz: int) -> dict:
     """Zero-filled host input arrays for one bucket shape at batch ``bsz``.
     ``t`` defaults to +inf, so the untouched allocation is a valid *idle*
-    bucket whose per-step cost matches a loaded one (the step does the same
-    gathers/wheres regardless of values) -- the auto-tuner measures on
-    exactly this, the AOT lowering takes its arg specs from it, and
-    ``_run_scan_bucket`` fills rows in place."""
+    bucket: the AOT lowering takes its arg specs from it and
+    ``_run_scan_bucket`` fills rows in place.  An idle cell is no timing
+    probe -- the Pallas step runs 2 steps per finite arrival, so none here;
+    the auto-tuner measures :func:`_probe_inputs` instead."""
     (mask, n_b, nodes_b, slots_b, f_b, kq, window, fc_ring, n_ep, n_copies,
      xtra) = shape_key
     flags = _mask_features(mask)
@@ -2229,6 +2231,26 @@ def _alloc_bucket_inputs(shape_key: tuple, bsz: int) -> dict:
         inp["rrt_p"][:, 0] = 1.0
         inp["adm_p"] = np.zeros((bsz, 2), dtype=fdt)
     return inp
+
+
+def _probe_inputs(shape_key: tuple, bsz: int) -> dict:
+    """The idle allocation with finite ascending arrival times in rows
+    ``[:n_b]`` of every cell: a timing probe that runs the full ``2 n_b``
+    step budget on either step path, as a loaded bucket of that shape does
+    (a step's cost does not depend on the values it steps over)."""
+    inp = _alloc_bucket_inputs(shape_key, bsz)
+    n_b = shape_key[1]
+    inp["t"][:, :n_b] = np.arange(n_b)
+    return inp
+
+
+def _exec_steps(chunk: list, bsz: int, n_steps: int, pallas: bool) -> int:
+    """Event steps one dispatched chunk runs: on the Pallas kernel each
+    cell's own arrival and completion steps (a padded cell none), on the
+    vmapped jnp scan the full ``n_steps`` budget in every batch slot."""
+    if pallas:
+        return 2 * sum(len(c.feats.t) for c in chunk)
+    return bsz * n_steps
 
 
 def _runner_kwargs(shape_key: tuple) -> tuple[dict, dict]:
@@ -2383,13 +2405,13 @@ def _bucket_chunk(shape_key: tuple, n_cells: int,
 
 def _autotune_chunk(shape_key: tuple, n_cells: int,
                     rec: dict | None = None) -> int:
-    """One-time chunk-size measurement for one bucket shape: time the idle
-    bucket (per-step cost is value-independent) at power-of-two batch sizes
-    under the :data:`SCAN_MEM_MB` footprint cap and keep the cells/sec
-    argmax.  Candidates ascend and ``max`` keeps the first maximum, so exact
-    ties resolve to the smaller batch; re-tuning the same resident entry is
-    a no-op (the choice is cached), which is what the determinism contract
-    promises."""
+    """One-time chunk-size measurement for one bucket shape: time the
+    :func:`_probe_inputs` bucket, which runs the full step budget like a
+    loaded one, at power-of-two batch sizes under the :data:`SCAN_MEM_MB`
+    footprint cap and keep the cells/sec argmax.  Candidates ascend and
+    ``max`` keeps the first maximum, so exact ties resolve to the smaller
+    batch; re-tuning the same resident entry is a no-op (the choice is
+    cached), which is what the determinism contract promises."""
     import jax
     import jax.numpy as jnp
 
@@ -2402,7 +2424,7 @@ def _autotune_chunk(shape_key: tuple, n_cells: int,
 
     def _rate(bsz: int) -> float:
         init_c, scan_c = _scan_runner(shape_key + (bsz,), rec)
-        inp = _alloc_bucket_inputs(shape_key, bsz)
+        inp = _probe_inputs(shape_key, bsz)
         best = np.inf
         for _ in range(3):       # min-of-3: robust to scheduler noise
             arrs = {k: jnp.asarray(v) for k, v in inp.items()}
@@ -2712,9 +2734,12 @@ def _run_scan_bucket(key: tuple, cells: list[_ScanCell]) -> list[tuple]:
     import jax
     import jax.numpy as jnp
 
+    from ..kernels import ops as _kops
+
     (mask, n_b, nodes_b, slots_b, f_b, kq, window, fc_ring, n_ep, n_copies,
      xtra) = key
     flags = _mask_features(mask)
+    pallas = _kops.event_step_path(**_runner_kwargs(key)[1]) == "pallas"
     freeze, dyn, hedge = flags["freeze"], flags["dyn"], flags["hedge"]
     cold, resil = flags["cold"], flags["res"]
     check = os.environ.get("REPRO_SCAN_CHECK") == "1"
@@ -2740,6 +2765,8 @@ def _run_scan_bucket(key: tuple, cells: list[_ScanCell]) -> list[tuple]:
                                        xtra_now, bsz), rec)
         _STEP_COUNTS["step_slots"] += bsz * (2 * n_b + xtra_now)
         _STEP_COUNTS["call_steps"] += 2 * sum(len(c.feats.t) for c in chunk)
+        _STEP_COUNTS["exec_steps"] += _exec_steps(chunk, bsz,
+                                                  2 * n_b + xtra_now, pallas)
         with _phase("dispatch", rec, "dispatch_s"), _x64_ctx(use64):
             # float64 buckets convert inputs *inside* enable_x64 --
             # quantizing kill/arrival/deadline times through float32 first

@@ -28,7 +28,13 @@ substitutions the TPU compiler (Mosaic) accepts:
   and stored once at the end (Mosaic cannot store at a dynamic lane);
 * ``cores``/``nodes`` arrive as scalar-prefetch operands in SMEM.
 
-Rows ``[:n]`` of the outputs are therefore the oracle's; row ``n`` is the
+Each cell runs its own trip count, a third scalar-prefetch operand: 2 steps
+per finite arrival time, capped at the bucket's ``n_steps``.  In base pull
+every step handles one event -- an arrival or a completion -- and a call
+waits only while every slot is busy, so a cell of ``n`` calls is done after
+exactly ``2 n`` steps and every later step is a no-op; a padded cell (all
+``t`` infinite) runs none and writes the initial zeros.  Rows ``[:n]`` of the
+outputs are therefore the oracle's at the full budget; row ``n`` is the
 shared garbage sentinel both paths scribble no-op events into.  The parity
 suite runs this kernel under ``interpret=True`` on CPU, and
 ``tests/test_tpu_compile.py`` compiles it for a described TPU v5e.
@@ -69,9 +75,9 @@ def _first(mask, ids, big):
     return jnp.min(jnp.where(mask, ids, big), keepdims=True)
 
 
-def _event_kernel(cores_ref, nodes_ref, t_ref, fnid_ref, p_ref, cost_ref,
-                  coef_ref, fnev_ref, *refs, n, n1p, n_nodes, n_slots,
-                  window, n_fns, kq, use_fc, horizon, n_steps, ft):
+def _event_kernel(cores_ref, nodes_ref, live_ref, t_ref, fnid_ref, p_ref,
+                  cost_ref, coef_ref, fnev_ref, *refs, n, n1p, n_nodes,
+                  n_slots, window, n_fns, kq, use_fc, horizon, ft):
     if use_fc:
         cumf_ref, *refs = refs
     (ai_ref, head_ref, fin_ref, idx_ref, busy_ref, chan_ref, ring_ref,
@@ -211,7 +217,7 @@ def _event_kernel(cores_ref, nodes_ref, t_ref, fnid_ref, p_ref, cost_ref,
             busy_ref[...], chan_ref[...], ring_ref[...], rsum_ref[...],
             rlen_ref[...], rpos_ref[...], last_ref[...], prev_ref[...],
             narr_ref[...], zf, zf, zf, jnp.zeros((1, n1p), dtype=i32))
-    out = lax.fori_loop(0, n_steps, step, init)
+    out = lax.fori_loop(0, live_ref[b], step, init)
     start_ref[...] = out[-4]
     finish_ref[...] = out[-3]
     prio_ref[...] = out[-2]
@@ -253,7 +259,8 @@ def event_step_pallas(clk, ctr, inp, *, interpret=False, n_nodes, n_slots,
     Same contract as the oracle path of ``repro.kernels.ops.event_step``:
     ``clk``/``ctr`` are the packed ``(B, f_len)`` / ``(B, i_len)`` carry
     planes, ``inp`` the batched bucket input dict; returns the
-    ``(start, finish, prio, node, aux)`` tuple with ``aux == {}``."""
+    ``(start, finish, prio, node, aux)`` tuple with ``aux == {}``.  Each
+    cell runs ``min(2 * finite arrivals, n_steps)`` event steps."""
     from ..core import fastpath as _fp     # lazy: core is heavy
 
     B, n1 = inp["t"].shape
@@ -281,11 +288,14 @@ def event_step_pallas(clk, ctr, inp, *, interpret=False, n_nodes, n_slots,
     out_row = pl.BlockSpec((None, 1, n1p), lambda b, *_: (b, 0, 0))
     kernel = partial(_event_kernel, n=n, n1p=n1p, n_nodes=n_nodes,
                      n_slots=n_slots, window=window, n_fns=n_fns, kq=kq,
-                     use_fc=use_fc, horizon=horizon, n_steps=n_steps, ft=ft)
+                     use_fc=use_fc, horizon=horizon, ft=ft)
+    # each cell's trip count: an arrival and a completion step per call
+    live = jnp.minimum(2 * jnp.sum(jnp.isfinite(inp["t"]), axis=1),
+                       n_steps).astype(jnp.int32)
     start, finish, prio, node = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,                       # cores, nodes
+            num_scalar_prefetch=3,               # cores, nodes, trip counts
             grid=(B,),
             in_specs=[block(x) for x in ops],
             out_specs=[out_row] * 4),
@@ -293,6 +303,6 @@ def event_step_pallas(clk, ctr, inp, *, interpret=False, n_nodes, n_slots,
         + [jax.ShapeDtypeStruct((B, 1, n1p), jnp.int32)],
         interpret=interpret,
         name="event_step",
-    )(inp["cores"], inp["nodes"], *ops)
+    )(inp["cores"], inp["nodes"], live, *ops)
     return (start[:, 0, :n1], finish[:, 0, :n1], prio[:, 0, :n1],
             node[:, 0, :n1], {})

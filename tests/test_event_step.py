@@ -134,6 +134,49 @@ class TestPallasParity:
                 np.asarray(a)[:, :n], np.asarray(b)[:, :n],
                 err_msg=f"{name} diverged (use_fc={use_fc})")
 
+    @pytest.mark.parametrize("use_fc", [False, True])
+    def test_mixed_lengths_run_their_own_steps(self, use_fc):
+        # one batch: a full cell (n = R), shorter cells of other lengths and
+        # an all-padded cell (n = 0); the kernel runs each cell's own 2n
+        # steps, the oracle the bucket's whole static budget
+        from repro.core import fastpath as _fp
+        from repro.kernels import ops
+
+        inp, flags, static, n = _smoke_inputs(use_fc, B=4)
+        lengths = (n, 5, 3, 0)
+        idle = _fp._alloc_bucket_inputs(
+            (0x2 if use_fc else 0x0, n, 2, 4, 2, 8, 4, 1, 1, 1, 0), 1)
+        for b, m in enumerate(lengths):
+            if m == 0:                    # the idle allocation's cell
+                for k in inp:
+                    inp[k][b] = idle[k][0]
+                continue
+            inp["t"][b, m:] = np.inf
+            for k in ("fnid", "p", "cost"):
+                inp[k][b, m:] = 0
+            if use_fc:
+                inp["cumf"][b, m + 1:] = inp["cumf"][b, m]
+            inp["fn_ev"][b] = n
+            for f in range(inp["fn_ev"].shape[1]):
+                ev = np.nonzero(inp["fnid"][b, :m] == f)[0]
+                inp["fn_ev"][b, f, :len(ev)] = ev
+        arrs = {k: jnp.asarray(v) for k, v in inp.items()}
+        clk, ctr = jax.vmap(partial(_fp._make_planes, **flags))(arrs)
+
+        ref = ops.event_step(clk, ctr, arrs, force="ref", **static)
+        pal = ops.event_step(clk, ctr, arrs, force="pallas",
+                             interpret=True, **static)
+        for name, a, b in zip(("start", "finish", "prio", "node"),
+                              ref[:4], pal[:4]):
+            a, b = np.asarray(a), np.asarray(b)
+            np.testing.assert_array_equal(
+                a[:, :n], b[:, :n], err_msg=f"{name} diverged "
+                f"(use_fc={use_fc}, lengths={lengths})")
+            for row, m in enumerate(lengths):
+                assert np.all(a[row, m:n] == 0), name   # never dispatched
+            # no step ran in the padded cell: not even the no-op sentinel
+            assert np.all(b[lengths.index(0)] == 0), name
+
     def test_supported_matrix(self):
         from repro.kernels.event_step import event_step_supported
 
@@ -234,6 +277,34 @@ class TestAutotune:
         tag = fp._bucket_tag(self.KEY)
         assert scan_cache_stats()["entries"][tag]["chunk"] == c1
         scan_cache_clear()
+
+    def test_probes_run_the_full_step_budget(self, monkeypatch):
+        # an idle cell runs no Pallas steps, so the tuner times probes
+        # whose every cell holds 2 * n_b finite-arrival steps
+        from repro.core import fastpath as fp
+
+        n_b = self.KEY[1]
+        _, step_kw = fp._runner_kwargs(self.KEY)
+        seen = []
+        real = fp._probe_inputs
+
+        def recording(key, bsz):
+            inp = real(key, bsz)
+            seen.append(inp)
+            return inp
+
+        scan_cache_clear()
+        monkeypatch.setattr(fp, "_probe_inputs", recording)
+        fp._autotune_chunk(self.KEY, 130)
+        scan_cache_clear()
+        assert [inp["t"].shape[0] for inp in seen] == [128, 256]
+        for inp in seen:
+            steps = 2 * np.isfinite(inp["t"]).sum(axis=1)
+            assert (steps == 2 * n_b).all()
+            assert (steps == step_kw["n_steps"]).all()
+            assert (np.diff(inp["t"][:, :n_b], axis=1) > 0).all()
+        idle = fp._alloc_bucket_inputs(self.KEY, 2)
+        assert not np.isfinite(idle["t"]).any()
 
     def test_no_tuning_below_default_chunk(self, monkeypatch):
         from repro.core import fastpath as fp

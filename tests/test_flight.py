@@ -389,11 +389,13 @@ def test_scan_timings_clear_resets_profile_latch():
         pass
     fastpath._SCAN_TIMINGS.append({"cells": 1})
     fastpath._STEP_COUNTS["step_slots"] += 8
+    fastpath._STEP_COUNTS["exec_steps"] += 8
     assert fastpath.scan_phase_totals()["fill"]["n"] == 1
     fastpath.scan_timings_clear()
     assert fastpath.scan_bucket_timings() == []
     assert fastpath.scan_phase_totals() == {"step_slots": 0,
-                                            "call_steps": 0}
+                                            "call_steps": 0,
+                                            "exec_steps": 0}
 
 
 # ---------------------------------------------------------------------------

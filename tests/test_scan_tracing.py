@@ -10,7 +10,9 @@ Contracts under test:
 * the per-chunk timing records and the phase totals agree: ``fill`` is the
   summed ``build_s``, and every record carries ``wait_s`` / ``unpack_s``;
 * self times are non-negative and add up to no more than the entry's wall;
-* ``step_slots`` / ``call_steps`` count padded and real event steps exactly;
+* ``step_slots`` / ``call_steps`` count padded and real event steps exactly,
+  and ``exec_steps`` the steps the step runs: every slot on the jnp scan,
+  each cell's own 2n on the Pallas kernel;
 * the step's XLA module is ``jit_event_step``, which every benchmark
   cell's ``step_pattern`` matches and the init module does not.
 """
@@ -20,6 +22,7 @@ import re
 import time
 from pathlib import Path
 
+import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
@@ -124,8 +127,41 @@ def test_step_counters_exact():
     # steps each; bucket two: 1 cell, 128-call rows; no extra steps
     assert totals["step_slots"] == 4 * 2 * 64 + 1 * 2 * 128
     assert totals["call_steps"] == 2 * (40 + 50 + 60 + 100)
+    # the vmapped jnp scan runs every slot it is dispatched
+    assert totals["exec_steps"] == totals["step_slots"]
     scan_timings_clear()
-    assert scan_phase_totals() == {"step_slots": 0, "call_steps": 0}
+    assert scan_phase_totals() == {"step_slots": 0, "call_steps": 0,
+                                   "exec_steps": 0}
+
+
+def test_exec_steps_on_the_pallas_path(monkeypatch):
+    # base-pull cells steered onto the Pallas kernel (interpreted here):
+    # the step runs the real calls' arrival and completion steps and
+    # nothing of the padded rows or the padded fourth cell
+    from repro.kernels import ops
+    from repro.kernels.event_step import event_step_supported
+
+    def pallas_where_supported(force=None, **static):
+        return "pallas" if event_step_supported(**static) else "jnp"
+
+    def pull(n: int) -> tuple:
+        return (_requests(n), 2, 2, "fifo", "pull")
+
+    batch = [pull(10), pull(12), pull(13)]
+    want = simulate_cluster_cells_scan(batch, metrics_only=True)
+    fp.scan_cache_clear()
+    monkeypatch.setattr(ops, "event_step_path", pallas_where_supported)
+    try:
+        scan_timings_clear()
+        got = simulate_cluster_cells_scan(batch, metrics_only=True)
+        totals = scan_phase_totals()
+    finally:
+        fp.scan_cache_clear()
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a.resp, b.resp)
+        assert a.max_c == b.max_c
+    assert totals["step_slots"] == 4 * 2 * 16
+    assert totals["exec_steps"] == totals["call_steps"] == 2 * 35
 
 
 @pytest.mark.filterwarnings("ignore:Some donated buffers were not usable")
