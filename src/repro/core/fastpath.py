@@ -36,9 +36,10 @@ policies) in array form:
   or evicts -- which holds for the default 32 GB node up to 10 cores (see
   :func:`scan_eligible`) and the cluster's 40 GB nodes up to ~13 (see
   :func:`cluster_scan_eligible`); ``warm=False`` cells instead carry
-  per-(node, fn) container tensors (MRU reuse, LRU eviction,
-  prewarm/create/evict costs) matching the reference pool
-  decision-for-decision.  Static-capacity arithmetic is float32, so
+  per-(node, fn) free-container counts where memory is ample, and a
+  per-node container table (MRU reuse, stem-cell replenish, create, LRU
+  eviction, blocked queue heads) where it is not, matching the reference
+  pool decision-for-decision.  Static-capacity arithmetic is float32, so
   agreement with the reference is within rounding for single nodes (~1e-6)
   and within the documented cluster tolerance for clusters (near-tie
   orderings can flip; see ``repro.core.sweep.CLUSTER_XCHECK_RTOL``);
@@ -561,10 +562,67 @@ def _cold_regime_ok(
     Worst-case resident containers per node: ``prewarm_count`` prewarms +
     ``cores`` busy + ``cores`` free per function (the release trim bound),
     plus one transient during the release-then-trim and replenish windows
-    each.  Ample memory means that bound times ``container_mb`` fits."""
-    n_fns = len({r.fn for r in requests})
+    each.  Ample memory means that bound times ``container_mb`` fits.
+    Cells outside it run on the container table instead, see
+    :func:`_pool_regime_ok`."""
+    return _ample(len({r.fn for r in requests}), cores, memory_mb,
+                  container_mb, prewarm_count)
+
+
+def _ample(n_fns: int, cores: int, memory_mb: int, container_mb: int,
+           prewarm_count: int = 2) -> bool:
+    """:func:`_cold_regime_ok`'s bound on ``n_fns`` distinct functions."""
     bound = container_mb * (prewarm_count + cores * (1 + n_fns) + 2)
     return bound <= memory_mb
+
+
+# stock OpenWhisk stem cells per invoker (ContainerPool.prewarm_count)
+_PREWARM_COUNT = 2
+
+
+def _fn_sizes(fns, container_mb: int) -> list[int]:
+    """Container memory of each function, as ``ContainerPool._size`` reads
+    it off the SeBS table (``container_mb`` for an unknown name)."""
+    return [int(SEBS_MEMORY_MB.get(fn, container_mb)) for fn in fns]
+
+
+def _pool_regime_ok(fns, cores: int, memory_mb: int, container_mb: int,
+                    assignment: str) -> bool:
+    """True when a ``warm=False`` cell outside the ample regime runs exactly
+    on the **container table** (``pool_w > 0`` buckets): a per-node table
+    of containers carrying function, memory, busy flag, ``last_used`` and
+    creation order, on which the step replays every path of
+    ``ContainerPool.acquire`` -- MRU warm pick, prewarm pick and a
+    replenish that can fail, create, LRU eviction then create, and a
+    blocked queue head -- and the release trim.
+
+    Every node must hold any single container (``memory_mb`` at least the
+    largest function or stem cell), so a blocked head always unblocks once
+    its node drains.  Pull cells must moreover never block: a blocked pull
+    invoker keeps its free slots, so the reference drains the global queue
+    into that node's own queue, ranked by the node's estimator -- a
+    node-local queue the pull step does not carry.  ``cores`` containers of
+    the largest size fitting in ``memory_mb`` rules it out: a head finds
+    only busy containers it cannot evict, at most ``cores - 1`` of them."""
+    sizes = _fn_sizes(fns, container_mb) + [int(container_mb)]
+    s_max = max(sizes)
+    if memory_mb < s_max:
+        return False
+    if assignment == "pull":
+        return cores * s_max <= memory_mb
+    return True
+
+
+def _pool_width(fns, cores: int, memory_mb: int, container_mb: int) -> int:
+    """Rows of a node's container table: as many containers as the node's
+    memory holds at the smallest size, capped at what the pool can ever
+    hold (``_PREWARM_COUNT`` stem cells, ``cores`` busy and ``cores`` idle
+    per function, counting the bucket's padded function axis so cells of
+    one grid share a width), rounded up to a multiple of 8."""
+    s_min = max(min(_fn_sizes(fns, container_mb) + [int(container_mb)]), 1)
+    cap = _PREWARM_COUNT + cores * (1 + _pow2(len(fns)))
+    w = max(1, min(int(memory_mb) // s_min, cap))
+    return -(-w // 8) * 8
 
 
 def scan_eligible(
@@ -580,14 +638,17 @@ def scan_eligible(
     float32): ours mode, known policy, and a container regime the kernel
     models -- either the always-warm regime where the §V-A warm-up provisions
     ``cores`` containers for *every* function (the pool never cold-starts,
-    evicts or blocks), or the ``warm=False`` ample-memory prewarm regime
-    (every cold start is a prewarm hit; see :func:`_cold_regime_ok`), where
-    the kernel carries per-(node, fn) container counts and charges the
-    prewarm management extra on cold dispatches."""
+    evicts or blocks), or ``warm=False``: in the ample-memory prewarm regime
+    (every cold start is a prewarm hit; see :func:`_cold_regime_ok`) the
+    kernel carries per-(node, fn) container counts, and on a memory-bound
+    node a container table with create, LRU eviction and a blocked queue
+    head (see :func:`_pool_regime_ok`)."""
     if mode != "ours" or policy not in POLICY_NAMES:
         return False
     if not warm:
-        return _cold_regime_ok(requests, cores, memory_mb, container_mb)
+        return (_cold_regime_ok(requests, cores, memory_mb, container_mb)
+                or _pool_regime_ok({r.fn for r in requests}, cores,
+                                   memory_mb, container_mb, "single"))
     fns = sorted({r.fn for r in requests})
     pool = _FastPool(memory_mb=memory_mb, container_mb=container_mb,
                      cores=cores, fn_memory=SEBS_MEMORY_MB)
@@ -661,7 +722,7 @@ class _PlaneLayout:
 
 def _make_state0(inp, *, n_nodes, n_slots, window, freeze, fc_push, dyn,
                  het, hedge, cold, dup, n_copies, fc_ring, res=False,
-                 stream=False):
+                 stream=False, pool_w=0):
     """Initial carry dict for one cell (the ``state0`` of the event scan).
 
     Split out of the kernel so three consumers share one definition: the
@@ -706,7 +767,37 @@ def _make_state0(inp, *, n_nodes, n_slots, window, freeze, fc_push, dyn,
             fcr=jnp.full((n_nodes, n_fns, fc_ring), -jnp.inf, dtype=ft),
             fcp=jnp.zeros((n_nodes, n_fns), dtype=jnp.int32),
         )
-    if cold:
+    if cold and pool_w:
+        # memory-bound pools: a container table per node, rows in no
+        # order (``psq`` is the creation order every tie breaks by).
+        # ``pfn`` is the function (-1 a stem cell, -2 an empty row); each
+        # node starts with the stem cells its memory holds, like
+        # ContainerPool.__post_init__
+        cmb, mem = inp["pcmb"], inp["pmem"]
+        w_ids = jnp.arange(pool_w, dtype=jnp.int32)
+        n_pw = jnp.clip(mem // jnp.maximum(cmb, 1), 0,
+                        _PREWARM_COUNT).astype(jnp.int32)
+        stem = w_ids < n_pw
+        row = (n_nodes, pool_w)
+        state0.update(
+            pfn=jnp.broadcast_to(jnp.where(stem, -1, -2), row
+                                 ).astype(jnp.int32),
+            pmb=jnp.broadcast_to(jnp.where(stem, cmb, 0), row
+                                 ).astype(jnp.int32),
+            pbz=jnp.zeros(row, dtype=bool),
+            plu=jnp.zeros(row, dtype=ft),
+            psq=jnp.broadcast_to(w_ids, row),
+            pnx=jnp.full(n_nodes, n_pw, dtype=jnp.int32),
+            ppk=jnp.full(n_nodes, n_pw, dtype=jnp.int32),
+            prow=jnp.zeros((n_nodes, n_slots), dtype=jnp.int32),
+            ncold=jnp.int32(0), nevt=jnp.int32(0), ncre=jnp.int32(0),
+            nblk=jnp.int32(0), coldq=jnp.zeros(n + 1, dtype=bool),
+        )
+        if freeze:
+            # a completion's dispatch loop continues at the same instant
+            # on the node in ``rdn`` (``rdt`` = +inf when none pends)
+            state0.update(rdt=inf, rdn=jnp.int32(0), pdone=jnp.int32(0))
+    elif cold:
         state0.update(
             # every pool starts empty in the warm=False regime (reference:
             # warm_functions=None skips warm_up); ample memory keeps the
@@ -828,10 +919,62 @@ def _make_planes(inp, **flags):
     return layout.pack(_make_state0(inp, **flags))
 
 
+# -- container-table row picks: one-hot masks over a node's rows.  Creation
+# order (``psq``) is unique among a node's live rows and is the order of the
+# reference's container list, so it breaks every tie the way a scan of that
+# list does.
+def _first_row(mask, sq):
+    """The earliest-created row of ``mask`` (a list scan's first hit)."""
+    import jax.numpy as jnp
+
+    return mask & (sq == jnp.min(jnp.where(mask, sq, _I32_MAX)))
+
+
+def _lru_row(mask, lu, sq):
+    """The least recently used row of ``mask``: smallest ``last_used``,
+    earliest created on ties (a stable sort over the list)."""
+    import jax.numpy as jnp
+
+    tie = mask & (lu == jnp.min(jnp.where(mask, lu, jnp.inf)))
+    return _first_row(tie, sq)
+
+
+def _mru_row(mask, lu, sq):
+    """The most recently used row of ``mask``, earliest created on ties
+    (the reference keeps the first strictly greater ``last_used``)."""
+    import jax.numpy as jnp
+
+    tie = mask & (lu == jnp.max(jnp.where(mask, lu, -jnp.inf)))
+    return _first_row(tie, sq)
+
+
+def _evict_lru(cand, lu, sq, mb, used, size, mem, go):
+    """ContainerPool's step 4: evict ``cand`` rows in LRU order while
+    ``used + size > mem`` and a candidate is left (when ``go``).  Returns
+    the evicted rows and the memory still in use; a head that still does
+    not fit has evicted every candidate, as the reference does."""
+    import jax
+    import jax.numpy as jnp
+
+    def cond(c):
+        ev, u = c
+        return go & (u + size > mem) & jnp.any(cand & ~ev)
+
+    def body(c):
+        ev, u = c
+        v = _lru_row(cand & ~ev, lu, sq)
+        return ev | v, u - jnp.sum(jnp.where(v, mb, 0))
+
+    return jax.lax.while_loop(cond, body, (jnp.zeros_like(cand), used))
+
+
+_I32_MAX = 2 ** 31 - 1
+
+
 def _scan_cell_kernel(clk, ctr, inp, *, n_nodes, n_slots, window, freeze,
                       use_fc, fc_push, dyn, het, hedge, cold, dup, n_copies,
                       n_ep, fc_ring, horizon, n_steps, res=False,
-                      stream=False):
+                      stream=False, pool_w=0):
     """One cell's event scan over a whole **cluster**: slot-occupancy and
     channel clocks carry a node axis, and the per-event dispatch includes the
     routing decision.  vmapped over the batch by the caller (via the
@@ -925,14 +1068,30 @@ def _scan_cell_kernel(clk, ctr, inp, *, n_nodes, n_slots, window, freeze,
     cancelled -- both runs occupy slots and feed the estimators, exactly
     like the reference.
 
-    ``cold=True`` compiles the ``warm=False`` **ample-memory prewarm
-    regime** (:func:`_cold_regime_ok`): estimator rings start empty, the
-    carry tracks per-(node, fn) free-container counts, a dispatch with no
-    free container is a prewarm cold start charging ``OURS_PREWARM_EXTRA``
-    on the management channel, and a release that would exceed the
-    ``cores`` per-function bound counts an eviction -- matching
-    ``ContainerPool`` exactly, where creations are provably zero and the
-    MRU/LRU container choice has no observable effect.
+    ``cold=True`` compiles the ``warm=False`` regimes; estimator rings
+    start empty in both.  With ``pool_w == 0``, the **ample-memory prewarm
+    regime** (:func:`_cold_regime_ok`): the carry tracks per-(node, fn)
+    free-container counts, a dispatch with no free container is a prewarm
+    cold start charging ``OURS_PREWARM_EXTRA`` on the management channel,
+    and a release that would exceed the ``cores`` per-function bound counts
+    an eviction -- matching ``ContainerPool`` exactly, where creations are
+    provably zero and the MRU/LRU container choice has no observable
+    effect.  With ``pool_w > 0``, **memory-bound nodes**
+    (:func:`_pool_regime_ok`): each node carries a ``pool_w``-row container
+    table (function, memory, busy, ``last_used``, creation order), and
+    every dispatch replays ``ContainerPool.acquire`` on it -- the MRU warm
+    container (earliest created on ties), else the earliest stem cell
+    (then replenished while memory allows), else a container created at
+    the function's size (``OURS_COLD_EXTRA``), evicting idle containers in
+    (``last_used``, creation) order first when memory is short, else the
+    head blocks: the call stays queued and the evictions stand.  A release
+    trims the function's idle containers to ``cores``, LRU first.  Only a
+    node's own completion can unblock it, and its dispatch loop may then
+    start several calls at that instant: under ``freeze`` a continuation
+    event (``rdt``, ranked before everything at its time) re-runs the
+    dispatch on that node until it blocks, drains or fills.  Pull cells
+    never block (the eligibility bound); ``nblk`` counts blocked attempts
+    so the host can refuse a cell that did.
 
     ``dyn=True`` compiles the **time-varying capacity** machinery on top:
     per-node activation times and a dead mask (the cell's
@@ -1002,6 +1161,11 @@ def _scan_cell_kernel(clk, ctr, inp, *, n_nodes, n_slots, window, freeze,
     fn_ids_ax = jnp.arange(ring0.shape[1])
     win_ids = jnp.arange(window)
     oreq_ids = jnp.arange(n + 1)     # one entry per *original* request
+    pool = cold and pool_w > 0       # memory-bound container table
+    pool_rd = pool and freeze        # ... with the blocked-head continuation
+    if pool:
+        fmb, pmem, pcmb = inp["fmb"], inp["pmem"], inp["pcmb"]
+        row_ids = jnp.arange(pool_w)
     if dup:
         # duplicate-mode copy axis, flattened into the request axis: queue
         # entry q = c*(n+1) + j is copy c of request j, so every frozen-
@@ -1107,6 +1271,10 @@ def _scan_cell_kernel(clk, ctr, inp, *, n_nodes, n_slots, window, freeze,
             # seq), but deadlines are estimate multiples and re-arrivals
             # jittered backoff sums -- measure-zero, like hedge
             cand = jnp.stack([t_a, t_c, jnp.min(to_t), jnp.min(rto)])
+        elif pool_rd:
+            # the continuation of a completion's dispatch loop runs before
+            # any other event at its instant, as the reference's does
+            cand = jnp.stack([st["rdt"], t_a, t_c])
         else:
             cand = jnp.stack([t_a, t_c])
         # argmin takes the *first* minimum: at equal times the stack order is
@@ -1120,9 +1288,11 @@ def _scan_cell_kernel(clk, ctr, inp, *, n_nodes, n_slots, window, freeze,
             # next chunk's first event, and the next chunk's candidate stack
             # replays the same same-instant precedence order
             none_left = none_left | (now >= t_stop)
-        off = 1 if dyn else 0
+        off = 1 if dyn or pool_rd else 0
         do_arr = (e == off) & ~none_left
         do_comp = (e == off + 1) & ~none_left
+        if pool_rd:
+            do_rd = (e == 0) & ~none_left
         if hedge:
             do_hedge = (e == (6 if dyn else 2)) & ~none_left
         if res:
@@ -1192,7 +1362,25 @@ def _scan_cell_kernel(clk, ctr, inp, *, n_nodes, n_slots, window, freeze,
         m_kn = (node_ids == kn) & do_comp
         busy = jnp.where(m_kn, busy - 1, busy)
         fin_s = jnp.where(m_kn[:, None] & (slot_ids == ks), inf, fin_s)
-        if cold:
+        if pool:
+            # -- container table, release half (ContainerPool.release +
+            # _trim_ours): the slot's row goes idle at ``now``; if f now
+            # has more than ``cores`` idle containers, its LRU idle row
+            # (last_used, then creation order) is evicted
+            m_nd = node_ids == kn
+            m_rl = (m_nd[:, None] & (row_ids == st["prow"][kn, ks])[None, :]
+                    & do_comp)
+            pbz = jnp.where(m_rl, False, st["pbz"])
+            plu = jnp.where(m_rl, now, st["plu"])
+            psq = st["psq"]
+            idl = (st["pfn"][kn] == f_done) & ~pbz[kn]
+            trim = do_comp & (jnp.sum(idl.astype(jnp.int32)) > cores)
+            m_tv = (m_nd[:, None] & _lru_row(idl, plu[kn], psq[kn])[None, :]
+                    & trim)
+            pfn = jnp.where(m_tv, -2, st["pfn"])
+            pmb = jnp.where(m_tv, 0, st["pmb"])
+            nevt = st["nevt"] + trim.astype(jnp.int32)
+        elif cold:
             # -- container segment, release half (ContainerPool.release +
             # _trim_ours): the freed container re-enters its (node, fn) free
             # pool unless the fn already holds ``cores`` free ones, in which
@@ -1604,6 +1792,8 @@ def _scan_cell_kernel(clk, ctr, inp, *, n_nodes, n_slots, window, freeze,
             k_d = jnp.where(do_ins, k_arr, kn)
             if dyn:
                 k_d = jnp.where(do_act, ka, k_d)
+            if pool_rd:
+                k_d = jnp.where(do_rd, st["rdn"], k_d)
             if res:
                 # a running-timeout frees a slot on the watched node and
                 # backfills there (scheduler.abort -> _dispatch)
@@ -1690,7 +1880,76 @@ def _scan_cell_kernel(clk, ctr, inp, *, n_nodes, n_slots, window, freeze,
                    & (busy[k_d] < cores) & has_q)
         else:
             can = ~none_left & (busy[k_d] < cores) & has_q
-        if cold:
+        if pool:
+            # -- container table, acquire half (ContainerPool.acquire) on
+            # node k_d for call j, attempted whenever ``can`` (the
+            # reference's dispatch loop reaching this head)
+            f_j = fnid[j]
+            size = fmb[f_j]
+            fn_k, mb_k, bz_k = pfn[k_d], pmb[k_d], pbz[k_d]
+            lu_k, sq_k = plu[k_d], psq[k_d]
+            warm_r = (fn_k == f_j) & ~bz_k
+            warm_hit = jnp.any(warm_r)
+            stem_r = fn_k == -1
+            has_stem = jnp.any(stem_r)
+            used = jnp.sum(mb_k)
+            # LRU eviction of idle rows until the function's size fits
+            # (every idle row qualifies: none holds f, else a warm hit)
+            go_ev = can & ~warm_hit & ~has_stem & (used + size > pmem)
+            evr, used_ev = _evict_lru((fn_k != -2) & ~bz_k, lu_k, sq_k, mb_k,
+                                      used, size, pmem, go_ev)
+            fn_e = jnp.where(evr, -2, fn_k)
+            fits = used_ev + size <= pmem
+            create = ~warm_hit & ~has_stem & fits
+            try_acq = can
+            can = can & (warm_hit | has_stem | fits)
+            empty = fn_e == -2
+            r_new = empty & (jnp.cumsum(empty.astype(jnp.int32)) == 1)
+            r_sel = jnp.where(warm_hit, _mru_row(warm_r, lu_k, sq_k),
+                              jnp.where(has_stem, _first_row(stem_r, sq_k),
+                                        r_new)) & can
+            m_new = r_new & create & can
+            nxt_sq = st["pnx"][k_d]
+            fn_n = jnp.where(r_sel, f_j, fn_e)
+            mb_n = jnp.where(m_new, size, jnp.where(evr, 0, mb_k))
+            sq_n = jnp.where(m_new, nxt_sq, sq_k)
+            nxt_sq = nxt_sq + m_new.any().astype(jnp.int32)
+            # a stem cell taken: top the stem pool back up while memory
+            # allows (the converted cell keeps its container_mb)
+            n_add = jnp.where(
+                has_stem & ~warm_hit & can,
+                jnp.clip(jnp.minimum(
+                    _PREWARM_COUNT - (jnp.sum(stem_r.astype(jnp.int32)) - 1),
+                    (pmem - used) // jnp.maximum(pcmb, 1)), 0,
+                    _PREWARM_COUNT), 0)
+            empty = fn_n == -2
+            rank = jnp.cumsum(empty.astype(jnp.int32))
+            add = empty & (rank <= n_add)
+            fn_n = jnp.where(add, -1, fn_n)
+            mb_n = jnp.where(add, pcmb, mb_n)
+            sq_n = jnp.where(add, nxt_sq + rank - 1, sq_n)
+            nxt_sq = nxt_sq + n_add
+            m_kr = (node_ids == k_d)[:, None] & try_acq
+            pfn = jnp.where(m_kr, fn_n[None, :], pfn)
+            pmb = jnp.where(m_kr, mb_n[None, :], pmb)
+            pbz = jnp.where(m_kr, (bz_k | r_sel)[None, :], pbz)
+            lu_n = jnp.where(r_sel, now, jnp.where(add, 0.0, lu_k))
+            plu = jnp.where(m_kr, lu_n[None, :], plu)
+            psq = jnp.where(m_kr, sq_n[None, :], psq)
+            pnx = jnp.where((node_ids == k_d) & try_acq, nxt_sq, st["pnx"])
+            ppk = jnp.maximum(st["ppk"], jnp.sum((pfn != -2).astype(
+                jnp.int32), axis=1))
+            nevt = nevt + jnp.where(try_acq,
+                                    jnp.sum(evr.astype(jnp.int32)), 0)
+            ncold = st["ncold"] + (can & ~warm_hit).astype(jnp.int32)
+            ncre = st["ncre"] + (can & create).astype(jnp.int32)
+            nblk = st["nblk"] + (try_acq & ~can).astype(jnp.int32)
+            coldq = jnp.where((oreq_ids == j) & can, ~warm_hit, st["coldq"])
+            cost_j = cost[j] + jnp.where(
+                warm_hit, 0.0,
+                jnp.where(has_stem, OURS_PREWARM_EXTRA, OURS_COLD_EXTRA))
+            r_idx = jnp.argmax(r_sel).astype(jnp.int32)
+        elif cold:
             # container acquire at dispatch (ContainerPool.acquire): a free
             # (node, fn) container is a warm hit; otherwise the prewarm pool
             # serves -- the ample-memory eligibility bound guarantees the
@@ -1742,6 +2001,8 @@ def _scan_cell_kernel(clk, ctr, inp, *, n_nodes, n_slots, window, freeze,
             dcnt = st["dcnt"] + can.astype(jnp.int32)
         if het and freeze:
             sspd = jnp.where(m_ds, eff, st["sspd"])
+        if pool:
+            prow = jnp.where(m_ds, r_idx, st["prow"])
         busy = jnp.where(m_kd & can, busy + 1, busy)
         qn = jnp.where(m_kd & can, qn - 1, qn)
         if freeze:
@@ -1776,6 +2037,16 @@ def _scan_cell_kernel(clk, ctr, inp, *, n_nodes, n_slots, window, freeze,
             # still absorb queued work
             still = do_act & can & (jnp.sum(qn) > 0) & (busy[ka] < cores)
             act_pend = jnp.where((node_ids == ka) & do_act, still, act_pend)
+        if pool_rd:
+            # a completion's dispatch loop goes on while the node has a
+            # free slot and a queue: arrivals need no continuation (a
+            # queue beside a free slot means the last attempt blocked and
+            # evicted every idle row, so a second head finds nothing)
+            more = (can & (do_comp | do_rd) & (busy[k_d] < cores)
+                    & jnp.any(pend & (node_of == k_d)))
+            rdt = jnp.where(more, now, inf)
+            rdn = jnp.where(more, k_d, st["rdn"])
+            pdone = st["pdone"] + do_comp.astype(jnp.int32)
 
         # per-dispatch record: scattered into per-request arrays after the
         # scan, so the carry holds no O(n) output state (the pull carry is
@@ -1789,7 +2060,13 @@ def _scan_cell_kernel(clk, ctr, inp, *, n_nodes, n_slots, window, freeze,
             nxt.update(pend=pend, fprio=fprio, node_of=node_of)
         if fc_push:
             nxt.update(fcr=fcr, fcp=fcp)
-        if cold:
+        if pool:
+            nxt.update(pfn=pfn, pmb=pmb, pbz=pbz, plu=plu, psq=psq, pnx=pnx,
+                       ppk=ppk, prow=prow, ncold=ncold, nevt=nevt,
+                       ncre=ncre, nblk=nblk, coldq=coldq)
+            if pool_rd:
+                nxt.update(rdt=rdt, rdn=rdn, pdone=pdone)
+        elif cold:
             nxt.update(freec=freec, ncold=ncold, nevt=nevt, coldq=coldq)
         if hedge:
             nxt.update(hedge_t=hedge_t, att=att, nbk=nbk, stolen=stolen,
@@ -1829,7 +2106,7 @@ def _scan_cell_kernel(clk, ctr, inp, *, n_nodes, n_slots, window, freeze,
                            window=window, freeze=freeze, fc_push=fc_push,
                            dyn=dyn, het=het, hedge=hedge, cold=cold,
                            dup=dup, n_copies=n_copies, fc_ring=fc_ring,
-                           res=res, stream=stream)
+                           res=res, stream=stream, pool_w=pool_w)
 
     def plane_step(planes, x):
         nxt, rec = step(layout.unpack(*planes), x)
@@ -1848,6 +2125,10 @@ def _scan_cell_kernel(clk, ctr, inp, *, n_nodes, n_slots, window, freeze,
     if cold:
         aux.update(ncold=state["ncold"], nevt=state["nevt"],
                    coldq=state["coldq"])
+    if pool:
+        aux.update(ncre=state["ncre"], ppk=state["ppk"], nblk=state["nblk"])
+        if pool_rd:
+            aux.update(pdone=state["pdone"])
     if hedge:
         # steal mode: every stolen call completes on its hedge target, so
         # distinct-stolen == steals won; dup mode: ``stolen`` marks
@@ -1947,8 +2228,11 @@ _PHASE_TOTALS: dict[str, list] = {}
 _PHASE_STACK: list[float] = []
 # event-step slots dispatched (padded cells and steps included), the
 # arrival + completion steps of the real calls in them, and the steps the
-# step actually runs (see ``_exec_steps``)
-_STEP_COUNTS = {"step_slots": 0, "call_steps": 0, "exec_steps": 0}
+# step actually runs (see ``_exec_steps``); for container-table buckets the
+# cell-nodes dispatched (padded cells included), their table rows, and the
+# peak resident containers of the real cell-nodes, summed
+_STEP_COUNTS = {"step_slots": 0, "call_steps": 0, "exec_steps": 0,
+                "pool_node_slots": 0, "pool_rows": 0, "pool_peak": 0}
 
 
 def _pow2(x: int) -> int:
@@ -1959,7 +2243,7 @@ def _pow2(x: int) -> int:
 def _bucket_tag(shape_key: tuple) -> str:
     """Human-readable stats/timing key for one bucket shape."""
     return ("mask=%#x,n=%d,nodes=%d,slots=%d,fns=%d,kq=%d,win=%d,ring=%d,"
-            "ep=%d,cp=%d,xtra=%d" % shape_key)
+            "ep=%d,cp=%d,pool=%d,xtra=%d" % shape_key)
 
 
 def scan_cache_stats() -> dict:
@@ -2010,7 +2294,10 @@ def scan_phase_totals() -> dict:
     plus three step counters: ``step_slots`` (cells x event steps
     dispatched, padding included), ``call_steps`` (the arrival and
     completion step of every real call in them) and ``exec_steps`` (the
-    event steps the step runs, :func:`_exec_steps`).  Each phase is also a
+    event steps the step runs, :func:`_exec_steps`), and three of the
+    container table: ``pool_node_slots`` (cell-nodes dispatched on it,
+    padded cells and nodes included), ``pool_rows`` (their table rows) and
+    ``pool_peak`` (the real cell-nodes' peak resident containers, summed).  Each phase is also a
     ``fastpath.<phase>`` span in a ``jax.profiler`` trace: ``entry`` (a
     batch entry), ``prepare`` (cell features and bucketing), ``tune``,
     ``compile``, ``fill``, ``dispatch``, ``wait`` and ``unpack``.  A
@@ -2078,7 +2365,10 @@ _CARRY_SEGMENTS: tuple[tuple[str, tuple[str, ...]], ...] = (
     ("freeze", ("pend", "fprio", "node_of")),
     ("use_fc", ()),                       # static-stream lookup, carry-free
     ("fc_push", ("fcr", "fcp")),
-    ("cold", ("freec", "ncold", "nevt", "coldq")),
+    ("cold", ("freec", "ncold", "nevt", "coldq",
+              # the container table of pool_w > 0 buckets
+              "pfn", "pmb", "pbz", "plu", "psq", "pnx", "ppk", "prow",
+              "ncre", "nblk", "rdt", "rdn", "pdone")),
     ("hedge", ("hedge_t", "att", "nbk", "stolen", "cring", "crsum", "crlen",
                "crpos", "qseq", "stepc", "ndone", "unhedge", "hedge_t2")),
     ("dup", ("done0", "win_start", "win_fin", "win_node", "start_q")),
@@ -2162,7 +2452,7 @@ def _alloc_bucket_inputs(shape_key: tuple, bsz: int) -> dict:
     probe -- the Pallas step runs 2 steps per finite arrival, so none here;
     the auto-tuner measures :func:`_probe_inputs` instead."""
     (mask, n_b, nodes_b, slots_b, f_b, kq, window, fc_ring, n_ep, n_copies,
-     xtra) = shape_key
+     pool_w, xtra) = shape_key
     flags = _mask_features(mask)
     freeze, use_fc = flags["freeze"], flags["use_fc"]
     dyn, het, hedge = flags["dyn"], flags["het"], flags["hedge"]
@@ -2230,6 +2520,11 @@ def _alloc_bucket_inputs(shape_key: tuple, bsz: int) -> dict:
         inp["rrt_p"] = np.zeros((bsz, 6), dtype=fdt)
         inp["rrt_p"][:, 0] = 1.0
         inp["adm_p"] = np.zeros((bsz, 2), dtype=fdt)
+    if pool_w:
+        # container table: each function's size, node and stem-cell memory
+        inp["fmb"] = np.zeros((bsz, f_b), dtype=np.int32)
+        inp["pmem"] = np.zeros(bsz, dtype=np.int32)
+        inp["pcmb"] = np.zeros(bsz, dtype=np.int32)
     return inp
 
 
@@ -2257,14 +2552,15 @@ def _runner_kwargs(shape_key: tuple) -> tuple[dict, dict]:
     """``(state_kw, step_kw)`` for one bucket shape: the plane packer's
     carry-shaping flags and the event-step kernel's full static kwargs."""
     (mask, n_req, n_nodes, n_slots, _, _, window, fc_ring, n_ep, n_copies,
-     xtra) = shape_key
+     pool_w, xtra) = shape_key
     flags = _mask_features(mask)
     state_kw = dict(n_nodes=n_nodes, n_slots=n_slots, window=window,
                     freeze=flags["freeze"], fc_push=flags["fc_push"],
                     dyn=flags["dyn"], het=flags["het"],
                     hedge=flags["hedge"], cold=flags["cold"],
                     dup=flags["dup"], n_copies=n_copies, fc_ring=fc_ring,
-                    res=flags["res"], stream=flags["stream"])
+                    res=flags["res"], stream=flags["stream"],
+                    pool_w=pool_w)
     step_kw = dict(state_kw, use_fc=flags["use_fc"], n_ep=n_ep,
                    horizon=DEFAULT_FC_HORIZON, n_steps=2 * n_req + xtra)
     return state_kw, step_kw
@@ -2351,7 +2647,8 @@ def _evict_runners(current: tuple) -> None:
 def _scan_runner(key: tuple, rec: dict | None = None):
     """AOT-compiled ``(init, scan)`` pair for one bucket shape at one chunk
     batch size: ``key = (feature_mask, n_req, n_nodes, n_slots, n_fns,
-    fn_queue_cap, window, fc_ring, n_ep, n_copies, xtra, batch)`` -- the
+    fn_queue_cap, window, fc_ring, n_ep, n_copies, pool_w, xtra, batch)``
+    (``pool_w`` the container-table rows, 0 off the table) -- the
     leading element is the :func:`_feature_mask` bitmask of enabled carry
     segments, the trailing one the padded chunk batch.  All batch sizes of
     one shape share a single LRU cache entry (see :class:`_CacheEntry`).
@@ -2379,7 +2676,7 @@ def _bucket_bytes(shape_key: tuple, bsz: int) -> int:
     temporaries + donation slack)."""
     per_cell = sum(v.nbytes
                    for v in _alloc_bucket_inputs(shape_key, 1).values())
-    n_b, xtra = shape_key[1], shape_key[10]
+    n_b, xtra = shape_key[1], shape_key[-1]
     itemsize = 8 if _use64(_mask_features(shape_key[0])) else 4
     outs = (2 * n_b + xtra) * 5 * itemsize
     return (per_cell * 3 + outs) * bsz
@@ -2456,6 +2753,9 @@ class _ScanCell:
     profile: object | None = None       # NodeSpeedProfile | None
     hedging: object | None = None       # HedgingSpec | None
     resilience: object | None = None    # ResilienceSpec | None
+    memory_mb: int = CLUSTER_MEMORY_MB
+    container_mb: int = CLUSTER_CONTAINER_MB
+    pool_w: int = 0      # container-table rows (0: off the table)
 
     @property
     def dyn(self) -> bool:
@@ -2575,6 +2875,24 @@ class _ScanCell:
             return 0
         return 2 * len(self.feats.t) * int(self.resilience.max_attempts)
 
+    def pool_budget(self) -> int:
+        """*Optimistic* extra scan steps for the blocked-head continuation
+        of a frozen-queue container-table cell: one per call a completion
+        starts beyond its first.  ``_run_scan_bucket`` verifies completion
+        (``pdone``) and re-runs a chunk at :meth:`pool_budget_full` when
+        this guess was short."""
+        if not self.pool_w or self.assignment == "pull":
+            return 0
+        return len(self.feats.t) // 4
+
+    def pool_budget_full(self) -> int:
+        """Strict bound on the continuation steps: each one either starts
+        a call (at most ``n``) or ends its completion's chain (at most one
+        a completion, ``n``)."""
+        if not self.pool_w or self.assignment == "pull":
+            return 0
+        return 2 * len(self.feats.t)
+
     def bucket(self) -> tuple:
         freeze = self.assignment != "pull"
         dyn = self.dyn
@@ -2602,7 +2920,8 @@ class _ScanCell:
                    if fc_push and len(self.feats.count) else 1)
         n_ep = (_pow2(max(1, len(self.profile.episodes)))
                 if self.het else 1)
-        extra = self.dyn_budget() + self.hedge_budget() + self.res_budget()
+        extra = (self.dyn_budget() + self.hedge_budget() + self.res_budget()
+                 + self.pool_budget())
         xtra = _pow2(extra) if extra else 0
         mask = _feature_mask(freeze=freeze, use_fc=use_fc, fc_push=fc_push,
                              cold=self.cold, hedge=self.hedge, dup=self.dup,
@@ -2610,7 +2929,7 @@ class _ScanCell:
         return (mask, _pow2(len(self.feats.t)),
                 _pow2(self.node_cap()), _pow2(self.cores),
                 _pow2(len(self.feats.fns)), kq, DEFAULT_WINDOW,
-                fc_ring, n_ep, self.n_copies, xtra)
+                fc_ring, n_ep, self.n_copies, self.pool_w, xtra)
 
 
 def _scan_check_outputs(tag: str, cell_idx: int, n: int,
@@ -2637,7 +2956,7 @@ def _fill_bucket_inputs(key: tuple, chunk: list, bsz: int) -> dict:
     :func:`_alloc_bucket_inputs` with rows ``[:len(chunk)]`` filled from the
     prepared cells (arrivals, fleet, policy coefficients, dynamics /
     heterogeneity / hedging / resilience parameters and the warm seed)."""
-    (_, _, nodes_b, _, f_b, _, window, _, n_ep, _, _) = key
+    (_, _, nodes_b, _, f_b, _, window, _, n_ep, _, pool_w, _) = key
     flags = _mask_features(key[0])
     use_fc, dyn, het = flags["use_fc"], flags["dyn"], flags["het"]
     hedge, resil = flags["hedge"], flags["res"]
@@ -2653,6 +2972,10 @@ def _fill_bucket_inputs(key: tuple, chunk: list, bsz: int) -> dict:
         inp["cnt"][b, :n] = f.count
         inp["cores"][b] = cell.cores
         inp["nodes"][b] = cell.nodes
+        if pool_w:
+            inp["fmb"][b, :len(f.fns)] = _fn_sizes(f.fns, cell.container_mb)
+            inp["pmem"][b] = cell.memory_mb
+            inp["pcmb"][b] = cell.container_mb
         if dyn:
             d = cell.dynamics
             inp["act0"][b, :cell.nodes] = 0.0
@@ -2722,6 +3045,20 @@ def _fill_bucket_inputs(key: tuple, chunk: list, bsz: int) -> dict:
     return inp
 
 
+def _pool_extras(ex: dict, aux: dict, b: int, cell: _ScanCell) -> None:
+    """A container-table cell's creations and peak residency; a blocked
+    head in a pull cell is refused (its reference drains the global queue
+    into the blocked node's own queue, which the pull step does not
+    carry, and the eligibility bound rules it out)."""
+    if int(aux["nblk"][b]) and cell.assignment == "pull":
+        raise RuntimeError(
+            f"a pull cell blocked a queue head {int(aux['nblk'][b])} "
+            "times on the container table; _pool_regime_ok should have "
+            "refused it")
+    ex["creations"] = int(aux["ncre"][b])
+    _STEP_COUNTS["pool_peak"] += int(np.sum(aux["ppk"][b][:cell.nodes]))
+
+
 def _run_scan_bucket(key: tuple, cells: list[_ScanCell]) -> list[tuple]:
     """Dispatch one shape bucket in auto-tuned chunks (each padded to a
     power-of-two batch) and return per-cell ``(start, finish, prio, node,
@@ -2737,11 +3074,12 @@ def _run_scan_bucket(key: tuple, cells: list[_ScanCell]) -> list[tuple]:
     from ..kernels import ops as _kops
 
     (mask, n_b, nodes_b, slots_b, f_b, kq, window, fc_ring, n_ep, n_copies,
-     xtra) = key
+     pool_w, xtra) = key
     flags = _mask_features(mask)
     pallas = _kops.event_step_path(**_runner_kwargs(key)[1]) == "pallas"
     freeze, dyn, hedge = flags["freeze"], flags["dyn"], flags["hedge"]
     cold, resil = flags["cold"], flags["res"]
+    pool_rd = pool_w > 0 and freeze
     check = os.environ.get("REPRO_SCAN_CHECK") == "1"
     n1 = n_b + 1
     use64 = _use64(flags)
@@ -2762,8 +3100,11 @@ def _run_scan_bucket(key: tuple, cells: list[_ScanCell]) -> list[tuple]:
         bsz = inp["cores"].shape[0]
         init_c, scan_c = _scan_runner((mask, n_b, nodes_b, slots_b, f_b,
                                        kq, window, fc_ring, n_ep, n_copies,
-                                       xtra_now, bsz), rec)
+                                       pool_w, xtra_now, bsz), rec)
         _STEP_COUNTS["step_slots"] += bsz * (2 * n_b + xtra_now)
+        if pool_w:
+            _STEP_COUNTS["pool_node_slots"] += bsz * nodes_b
+            _STEP_COUNTS["pool_rows"] += bsz * nodes_b * pool_w
         _STEP_COUNTS["call_steps"] += 2 * sum(len(c.feats.t) for c in chunk)
         _STEP_COUNTS["exec_steps"] += _exec_steps(chunk, bsz,
                                                   2 * n_b + xtra_now, pallas)
@@ -2828,6 +3169,22 @@ def _run_scan_bucket(key: tuple, cells: list[_ScanCell]) -> list[tuple]:
                 full = max(c.dyn_budget() + c.hedge_budget()
                            + c.res_budget_full() for c in chunk)
                 res = _collect(_dispatch(inp, _pow2(full), rec, chunk), rec)
+        if pool_rd:
+            pdone_b = res[4]["pdone"]
+            if any(int(pdone_b[b]) != len(chunk[b].feats.t)
+                   for b in range(len(chunk))):
+                # the optimistic continuation budget fell short (blocked
+                # heads released many calls at once): re-run the chunk at
+                # the strict bound
+                full = max(c.pool_budget_full() for c in chunk)
+                res = _collect(_dispatch(inp, _pow2(full), rec, chunk), rec)
+                pdone_b = res[4]["pdone"]
+                for b, cell in enumerate(chunk):
+                    if int(pdone_b[b]) != len(cell.feats.t):
+                        raise RuntimeError(
+                            "container-table scan step budget exhausted "
+                            f"at the strict bound ({full}); this is a "
+                            "kernel budget bug")
         return res
 
     def _unpack(lo: int, chunk: list, inp: dict, res) -> None:
@@ -2844,6 +3201,8 @@ def _run_scan_bucket(key: tuple, cells: list[_ScanCell]) -> list[tuple]:
                     ex.update(cold_starts=int(aux["ncold"][b]),
                               evictions=int(aux["nevt"][b]),
                               coldq=aux["coldq"][b])
+                if pool_w:
+                    _pool_extras(ex, aux, b, chunk[b])
                 if check:
                     _scan_check_outputs(
                         tag, lo + b, len(chunk[b].feats.t),
@@ -2952,6 +3311,7 @@ class ScanMetrics:
     backups: int = 0
     steals: int = 0
     nodes_used: int = 0
+    creations: int = 0
 
 
 def _cell_scan_metrics(cell: _ScanCell, finish, extras,
@@ -2985,7 +3345,8 @@ def _cell_scan_metrics(cell: _ScanCell, finish, extras,
         evictions=ex.get("evictions", 0),
         failures=ex.get("failures", 0), backups=ex.get("backups", 0),
         steals=ex.get("steals", 0),
-        nodes_used=ex.get("nodes_used", cell.nodes))
+        nodes_used=ex.get("nodes_used", cell.nodes),
+        creations=ex.get("creations", 0))
 
 
 def _run_scan_cells(cells: list[_ScanCell],
@@ -3064,7 +3425,7 @@ def _write_back(cell: _ScanCell, start, finish, prio, node,
         meta["nodes"] = cell.nodes
         meta["assignment"] = cell.assignment
     failures = backups = steals = 0
-    cold_starts = evictions = 0
+    cold_starts = evictions = creations = 0
     timed_out = shed = retries_issued = 0
     wasted_work = 0.0
     nodes_used = cell.nodes
@@ -3075,6 +3436,7 @@ def _write_back(cell: _ScanCell, start, finish, prio, node,
         steals = extras.get("steals", 0)
         cold_starts = extras.get("cold_starts", 0)
         evictions = extras.get("evictions", 0)
+        creations = extras.get("creations", 0)
         timed_out = extras.get("timed_out", 0)
         shed = extras.get("shed", 0)
         retries_issued = extras.get("retries_issued", 0)
@@ -3089,7 +3451,7 @@ def _write_back(cell: _ScanCell, start, finish, prio, node,
                             for k in range(nodes_used)])
     return SimResult(
         requests=cell.requests, cold_starts=cold_starts,
-        evictions=evictions, creations=0, failures=failures,
+        evictions=evictions, creations=creations, failures=failures,
         backups_issued=backups, steals_won=steals, nodes_used=nodes_used,
         timeline=timeline, timed_out=timed_out, shed=shed,
         retries_issued=retries_issued, wasted_work=wasted_work, meta=meta)
@@ -3154,12 +3516,24 @@ def _single_cell(item: tuple, feats, memory_mb: int, container_mb: int,
                                       container_mb=container_mb):
         raise ScanRejected(
             "scan backend requires the ours regime, a known policy and "
-            "(cold cells) ample container memory "
+            "(cold cells) node memory that holds any one container "
             f"(policy={policy!r}, cores={cores}, warm={warm}); use "
             "backend='vectorized' for the general exact fast path")
-    return _ScanCell(requests=requests, feats=feats(requests),
-                     cores=cores, nodes=1, policy=policy,
-                     assignment="single", warm=warm)
+    f = feats(requests)
+    return _ScanCell(requests=requests, feats=f, cores=cores, nodes=1,
+                     policy=policy, assignment="single", warm=warm,
+                     memory_mb=memory_mb, container_mb=container_mb,
+                     pool_w=_cell_pool_width(f, cores, warm, memory_mb,
+                                             container_mb))
+
+
+def _cell_pool_width(f: _Arrivals, cores: int, warm: bool,
+                     memory_mb: int, container_mb: int) -> int:
+    """Container-table rows of a cell: 0 for warm cells and for cold cells
+    in the ample-memory prewarm regime, which keep the count path."""
+    if warm or _ample(len(f.fns), cores, memory_mb, container_mb):
+        return 0
+    return _pool_width(f.fns, cores, memory_mb, container_mb)
 
 
 # ---------------------------------------------------------------------------
@@ -3184,8 +3558,10 @@ def cluster_scan_eligible(
     float32 rounding: ours mode, known policy, a container regime the kernel
     models (always-warm -- the §V-A warm-up provisions ``cores`` containers
     per function on the cluster's 40 GB nodes, so up to ~13 cores for the
-    full SeBS set -- or the ``warm=False`` ample-memory prewarm regime, see
-    :func:`_cold_regime_ok`), and
+    full SeBS set -- or ``warm=False``: the ample-memory prewarm regime, see
+    :func:`_cold_regime_ok`, or memory-bound nodes on the container table,
+    see :func:`_pool_regime_ok`, on a static uniform fleet without
+    hedging), and
 
     * ``assignment="pull"`` -- any policy (priorities are re-ranked at pull
       time from the controller estimator, exactly like the reference), or
@@ -3248,7 +3624,14 @@ def cluster_scan_eligible(
                     or any(at < 0 for _, at in dynamics.fail)):
                 return False
     if not warm:
-        return _cold_regime_ok(requests, cores, memory_mb, container_mb)
+        if _cold_regime_ok(requests, cores, memory_mb, container_mb):
+            return True
+        # memory-bound nodes: the container table, on a static uniform
+        # fleet without hedging
+        return (not dyn and hedging is None
+                and (profile is None or profile.is_uniform)
+                and _pool_regime_ok({r.fn for r in requests}, cores,
+                                    memory_mb, container_mb, assignment))
     fns = sorted({r.fn for r in requests})
     pool = _FastPool(memory_mb=memory_mb, container_mb=container_mb,
                      cores=cores, fn_memory=SEBS_MEMORY_MB)
@@ -3273,7 +3656,10 @@ def simulate_cluster_cells_scan(
     :class:`~repro.core.stragglers.NodeSpeedProfile`) heterogeneous node
     speeds, ``hedging`` (a :class:`~repro.core.stragglers.HedgingSpec`)
     straggler work stealing or duplicate racing, and ``warm=False`` the
-    cold-start/eviction regime -- all modelled inside the scan step, in any
+    cold-start/eviction regime -- per-(node, fn) container counts where
+    memory is ample, a per-node container table (create, LRU eviction,
+    blocked heads) on memory-bound nodes, each in a bucket of its own
+    keyed by the table width -- all modelled inside the scan step, in any
     combination :func:`cluster_scan_eligible` accepts.
 
     Every cell must satisfy :func:`cluster_scan_eligible` (raises
@@ -3282,7 +3668,8 @@ def simulate_cluster_cells_scan(
     :class:`~repro.core.cluster.Cluster`; agreement is within the documented
     cluster cross-check tolerance (float32 clocks, index-order
     tie-breaking), see ``repro.core.sweep.CLUSTER_XCHECK_RTOL``; lost
-    request, backup/steal and cold-start/eviction counts are exact.
+    request, backup/steal and cold-start/eviction/creation counts are
+    exact.
     ``metrics_only=True`` skips the per-request write-back and returns
     :class:`ScanMetrics` rows (bit-identical aggregate metrics, shareable
     workloads).
@@ -3328,16 +3715,20 @@ def _cluster_cell(item: tuple, feats, memory_mb: int, container_mb: int,
         raise ScanRejected(
             "scan cluster backend requires the ours regime with "
             "supported dynamics/heterogeneity/hedging/resilience and, "
-            "for cold cells, ample container memory "
+            "for cold cells, ample container memory or a memory-bound "
+            "pool the container table models "
             f"(policy={policy!r}, nodes={nodes}, cores={cores}, "
             f"assignment={assignment!r}, warm={warm}, "
             f"dynamics={dynamics!r}, hedging={hedging!r}, "
             f"resilience={resilience!r}); use backend='reference'")
-    return _ScanCell(requests=requests, feats=feats(requests),
-                     cores=cores, nodes=nodes, policy=policy,
-                     assignment=assignment, lb=lb, warm=warm,
+    f = feats(requests)
+    return _ScanCell(requests=requests, feats=f, cores=cores, nodes=nodes,
+                     policy=policy, assignment=assignment, lb=lb, warm=warm,
                      dynamics=dynamics, profile=profile,
-                     hedging=hedging, resilience=resilience)
+                     hedging=hedging, resilience=resilience,
+                     memory_mb=memory_mb, container_mb=container_mb,
+                     pool_w=_cell_pool_width(f, cores, warm, memory_mb,
+                                             container_mb))
 
 
 def simulate_cluster_scan(

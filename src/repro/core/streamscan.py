@@ -582,8 +582,14 @@ def simulate_cluster_stream(
 
         if not _cold_regime_ok([_F(f) for f in stream.fns],
                                cores_per_node, memory_mb, container_mb):
+            # memory-bound single-shot cells carry a per-node container
+            # table (fastpath pool_w > 0); the chunk handoff does not carry
+            # one across chunk boundaries yet
             raise ValueError(
-                "warm=False stream outside the ample-memory prewarm regime")
+                "warm=False stream outside the ample-memory prewarm regime: "
+                "memory-bound nodes need the container table, and the "
+                "chunk handoff does not carry a table from one chunk to "
+                "the next")
     dyn = dynamics is not None and not dynamics.is_static
     het = profile is not None and not profile.is_uniform
     hedge = hedging is not None and assignment == "push"
@@ -767,7 +773,7 @@ def simulate_cluster_stream(
         xtra = max(xtra, _pow2(need_x))
 
         shape_key = (mask, n_b, nodes_b, slots_b, f_b, 1, window, fc_ring,
-                     n_ep, 1, xtra)
+                     n_ep, 1, 0, xtra)
         peak_rows = max(peak_rows, n_b)
 
         # ---- fill inputs -------------------------------------------------
@@ -855,7 +861,7 @@ def simulate_cluster_stream(
         attempt = 0
         while True:
             key = (mask, n_b, nodes_b, slots_b, f_b, 1, window, fc_ring,
-                   n_ep, 1, xtra, 1)
+                   n_ep, 1, 0, xtra, 1)
             init_c, scan_c = _scan_runner(key)
             with _x64_ctx(use64):
                 arrs = {k: jnp.asarray(v) for k, v in inp.items()}

@@ -145,7 +145,7 @@ class TestPallasParity:
         inp, flags, static, n = _smoke_inputs(use_fc, B=4)
         lengths = (n, 5, 3, 0)
         idle = _fp._alloc_bucket_inputs(
-            (0x2 if use_fc else 0x0, n, 2, 4, 2, 8, 4, 1, 1, 1, 0), 1)
+            (0x2 if use_fc else 0x0, n, 2, 4, 2, 8, 4, 1, 1, 1, 0, 0), 1)
         for b, m in enumerate(lengths):
             if m == 0:                    # the idle allocation's cell
                 for k in inp:
@@ -253,7 +253,7 @@ class TestMetricsOnlyParity:
 @needs_jax
 class TestAutotune:
     # tiny base-pull bucket: tuning compiles two candidate runners only
-    KEY = (0x0, 16, 2, 4, 4, 4, 4, 1, 1, 1, 0)
+    KEY = (0x0, 16, 2, 4, 4, 4, 4, 1, 1, 1, 0, 0)
 
     def test_tunes_once_and_memoizes(self, monkeypatch):
         from repro.core import fastpath as fp

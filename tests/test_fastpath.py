@@ -302,7 +302,22 @@ class TestScanBackend:
         assert not scan_eligible(reqs, cores=20, policy="sept")  # partial warm
         # the cold regime is in-matrix when memory is ample...
         assert scan_eligible(reqs, cores=10, policy="sept", warm=False)
-        # ...but a tight pool (evict-for-memory reachable) stays reference
+        # ...and on a memory-bound node (the container table: create, LRU
+        # eviction, blocked heads), agreeing with the reference exactly...
+        assert scan_eligible(reqs, cores=10, policy="sept", warm=False,
+                             memory_mb=4096)
+        kw = dict(cores=10, policy="sept", warm=False, memory_mb=4096)
+        ref = simulate_single_node(generate_burst(cores=10, intensity=20,
+                                                  seed=0), **kw)
+        got = simulate_single_node(generate_burst(cores=10, intensity=20,
+                                                  seed=0), backend="scan",
+                                   **kw)
+        assert (got.cold_starts, got.evictions, got.creations) == \
+            (ref.cold_starts, ref.evictions, ref.creations)
+        assert ref.evictions > 0 and ref.creations > 0
+        assert max(abs(a.c - b.c) for a, b in zip(got.requests,
+                                                  ref.requests)) < 1e-9
+        # ...but a node smaller than one container stays reference
         assert not scan_eligible(reqs, cores=10, policy="sept", warm=False,
                                  memory_mb=512)
         assert not scan_eligible(reqs, cores=10, policy="sept",

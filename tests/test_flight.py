@@ -385,6 +385,7 @@ def test_scan_timings_clear_resets_profile_latch():
     # scan_timings_clear() resets the chunk records, the phase totals and
     # the step counters together, so a later window reads only its own
     from repro.core import fastpath
+    fastpath.scan_timings_clear()        # whatever ran earlier in this process
     with fastpath._phase("fill", {"build_s": 0.0}, "build_s"):
         pass
     fastpath._SCAN_TIMINGS.append({"cells": 1})
@@ -395,7 +396,10 @@ def test_scan_timings_clear_resets_profile_latch():
     assert fastpath.scan_bucket_timings() == []
     assert fastpath.scan_phase_totals() == {"step_slots": 0,
                                             "call_steps": 0,
-                                            "exec_steps": 0}
+                                            "exec_steps": 0,
+                                            "pool_node_slots": 0,
+                                            "pool_rows": 0,
+                                            "pool_peak": 0}
 
 
 # ---------------------------------------------------------------------------

@@ -93,11 +93,27 @@ class TestEligibility:
         assert not cluster_scan_eligible(reqs, 2, 18, "fc")
 
     def test_cold_regime_eligibility(self):
-        """warm=False is in-matrix in the ample-memory prewarm regime; a
-        tight pool (evictions-for-memory reachable) stays reference-only."""
+        """warm=False is in-matrix in the ample-memory prewarm regime and
+        on memory-bound nodes (the container table: creates and LRU
+        evictions, exact counters); a node smaller than one container, or
+        a pull node whose head could block, stays reference-only."""
         assert cluster_scan_eligible(_burst(), 2, 6, "fc", warm=False)
+        # 6 GB with 256 MB stem cells: below the ample bound, yet six
+        # 1 GB containers fit
+        tight = dict(warm=False, memory_mb=6144, container_mb=256)
+        assert cluster_scan_eligible(_burst(), 2, 6, "fc", **tight)
+        ref = simulate_cluster(_burst(), 2, 6, "fc", backend="reference",
+                               **tight)
+        got = simulate_cluster(_burst(), 2, 6, "fc", backend="scan",
+                               **tight)
+        assert got.evictions == ref.evictions > 0
+        assert got.creations == ref.creations > 0
+        assert got.cold_starts == ref.cold_starts
+        assert _worst_rel(_metrics(got), _metrics(ref)) <= 1e-12
         assert not cluster_scan_eligible(_burst(), 2, 6, "fc", warm=False,
                                          memory_mb=512)
+        assert not cluster_scan_eligible(_burst(), 2, 6, "fc", warm=False,
+                                         memory_mb=4096)
 
     def test_defaults_mirror_cluster_config(self):
         """fastpath's eligibility constants must track ClusterConfig, or the
@@ -290,12 +306,12 @@ class TestCompileCache:
         cell = _ScanCell(requests=reqs, feats=_arrival_features(reqs),
                          cores=6, nodes=3, policy="fc", assignment="pull")
         (mask, n_b, nodes_b, slots_b, f_b, kq, window, fc_ring, n_ep,
-         n_copies, xtra) = cell.bucket()
+         n_copies, pool_w, xtra) = cell.bucket()
         flags = _mask_features(mask)
         assert not flags["freeze"] and flags["use_fc"]
         assert not flags["fc_push"] and not flags["cold"]
         assert not any(flags[k] for k in ("dyn", "het", "hedge", "dup"))
-        assert xtra == 0 and n_copies == 1
+        assert xtra == 0 and n_copies == 1 and pool_w == 0
         for v in (n_b, nodes_b, slots_b, f_b, kq):
             assert v & (v - 1) == 0                   # powers of two
         assert n_b >= len(reqs) and nodes_b >= 3 and slots_b >= 6

@@ -131,7 +131,8 @@ def test_step_counters_exact():
     assert totals["exec_steps"] == totals["step_slots"]
     scan_timings_clear()
     assert scan_phase_totals() == {"step_slots": 0, "call_steps": 0,
-                                   "exec_steps": 0}
+                                   "exec_steps": 0, "pool_node_slots": 0,
+                                   "pool_rows": 0, "pool_peak": 0}
 
 
 def test_exec_steps_on_the_pallas_path(monkeypatch):
@@ -166,7 +167,7 @@ def test_exec_steps_on_the_pallas_path(monkeypatch):
 
 @pytest.mark.filterwarnings("ignore:Some donated buffers were not usable")
 def test_step_module_name_matches_step_pattern():
-    key = (0, 8, 2, 2, 1, 8, 10, 1, 1, 1, 0)
+    key = (0, 8, 2, 2, 1, 8, 10, 1, 1, 1, 0, 0)
     init_fn, scan_fn = fp._runner_fns(key)
     specs = {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
              for k, v in fp._alloc_bucket_inputs(key, 2).items()}

@@ -27,11 +27,15 @@ from repro.kernels import ops  # noqa: E402
 pytestmark = pytest.mark.filterwarnings("ignore:Some donated buffers")
 
 # (feature mask, n, nodes, slots, fns, kq, window, fc ring, episodes,
-#  copies, extra steps) -- bucket keys the chip smoke run dispatches
-PALLAS_BASE = (0x0, 512, 4, 8, 16, 64, 10, 1, 1, 1, 0)      # phase A
-PALLAS_FC = (0x2, 1024, 4, 8, 16, 64, 10, 1, 1, 1, 0)       # phase A, FC
-DYN_PULL = (0x82, 512, 8, 8, 16, 32, 10, 1, 1, 1, 256)      # phase B
-PLANET_STREAM = (0x280, 4096, 128, 1, 16384, 1, 10, 1, 1, 1, 256)  # phase C
+#  copies, container-table rows, extra steps) -- bucket keys the chip
+#  smoke run dispatches
+PALLAS_BASE = (0x0, 512, 4, 8, 16, 64, 10, 1, 1, 1, 0, 0)      # phase A
+PALLAS_FC = (0x2, 1024, 4, 8, 16, 64, 10, 1, 1, 1, 0, 0)       # phase A, FC
+DYN_PULL = (0x82, 512, 8, 8, 16, 32, 10, 1, 1, 1, 0, 256)      # phase B
+PLANET_STREAM = (0x280, 4096, 128, 1, 16384, 1, 10, 1, 1, 1, 0, 256)  # phase C
+# the memory-bound cold pull bucket with its 248-row container table per
+# node (sebs.tight-cold's shape at a shorter request axis)
+COLD_TABLE = (0x8, 512, 4, 32, 16, 64, 10, 1, 1, 1, 248, 0)
 
 
 @pytest.fixture(scope="module")
@@ -84,8 +88,9 @@ def test_pallas_event_step_compiles(one_chip, monkeypatch, key):
     assert "tpu_custom_call" in scan_c.as_text()
 
 
-@pytest.mark.parametrize("key", [DYN_PULL, PLANET_STREAM],
-                         ids=["f64_autoscale_kills", "f64_planet_stream"])
+@pytest.mark.parametrize("key", [DYN_PULL, PLANET_STREAM, COLD_TABLE],
+                         ids=["f64_autoscale_kills", "f64_planet_stream",
+                              "f64_cold_table"])
 def test_jnp_bucket_runner_compiles(one_chip, monkeypatch, key):
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
     _, step_kw = fp._runner_kwargs(key)
