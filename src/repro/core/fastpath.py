@@ -18,14 +18,14 @@ policies) in array form:
   eviction order, same event tie-breaking), so metrics agree to the bit --
   including cold starts, tight-memory eviction and ``warm=False`` runs.
 * :class:`ScanBackend` / :func:`simulate_cells_scan` /
-  :func:`simulate_cluster_cells_scan` -- a ``jax.lax.scan`` variant that runs
-  a whole batch of cells as one scan over padded request tensors (one event
-  per step, cells vmapped).  The kernel is **multi-node**: slot occupancy and
-  management-channel clocks carry a node axis, and the per-event dispatch
-  computes the cluster routing decision (pull most-free-slots, push
-  least-loaded / home-invoker) inside the scan step, so an entire N-node
-  cluster cell is one scan and a whole nodes x intensity x policy grid is a
-  handful of bucketed XLA dispatches.  Capacity is **time-varying**: cells
+  :func:`simulate_cluster_cells_scan` -- an event-step loop that runs a
+  whole batch of cells as one loop over padded request tensors (one event
+  per step, cells vmapped, stopped once every cell is idle).  The kernel is
+  **multi-node**: slot occupancy and management-channel clocks carry a node
+  axis, and the per-event dispatch computes the cluster routing decision
+  (pull most-free-slots, push least-loaded / home-invoker) inside the scan
+  step, so an entire N-node cluster cell is one scan and a whole nodes x
+  intensity x policy grid is a handful of bucketed XLA dispatches.  Capacity is **time-varying**: cells
   with a :class:`~repro.core.cluster.ClusterDynamics` carry per-node
   activation masks updated inside the step -- autoscaler ticks provision
   nodes after the configured delay, scheduled kills wipe a node and re-queue
@@ -480,7 +480,7 @@ register_backend(VectorizedBackend())
 
 
 # ---------------------------------------------------------------------------
-# jax.lax.scan batched variant: a whole grid as one scan
+# batched event-step loop: a whole grid as one scan
 # ---------------------------------------------------------------------------
 # priority = a*r' + b*rbar + (c + d*count) * E[p]  -- all five policies are
 # points in this 4-coefficient family, so one scan body serves the whole grid
@@ -971,21 +971,23 @@ def _evict_lru(cand, lu, sq, mb, used, size, mem, go):
 _I32_MAX = 2 ** 31 - 1
 
 
-def _scan_cell_kernel(clk, ctr, inp, *, n_nodes, n_slots, window, freeze,
-                      use_fc, fc_push, dyn, het, hedge, cold, dup, n_copies,
-                      n_ep, fc_ring, horizon, n_steps, res=False,
-                      stream=False, pool_w=0):
-    """One cell's event scan over a whole **cluster**: slot-occupancy and
+def _cell_step(inp, *, n_nodes, n_slots, window, freeze, use_fc, fc_push,
+               dyn, het, hedge, cold, dup, n_copies, n_ep, fc_ring, horizon,
+               res=False, stream=False, pool_w=0):
+    """One cell's event step over a whole **cluster**: slot-occupancy and
     channel clocks carry a node axis, and the per-event dispatch includes the
-    routing decision.  vmapped over the batch by the caller (via the
-    ``repro.kernels.ops.event_step`` dispatcher); ``inp`` is a dict of
-    per-cell arrays (see ``_run_scan_bucket``) and ``(clk, ctr)`` is the
-    cell's initial carry as a packed :class:`_PlaneLayout` plane pair
-    (produced by :func:`_make_planes`, whose buffers the runner donates).
-    The ``lax.scan`` carry is that same plane pair -- two contiguous
-    tensors -- with the per-segment dict view reconstructed by static
-    slicing inside the step, so XLA fuses the whole step into a handful of
-    kernels instead of threading ~20 small carry arrays.
+    routing decision.  Returns ``(layout, pick, step)``: the cell's
+    :class:`_PlaneLayout`, ``pick(st) -> (e, now, none_left, kflat)`` (the
+    next event: its candidate index, its time, whether the cell has none
+    left, and the slot that completes first) and ``step(st, ev) -> (st',
+    record)``, which handles the event ``ev = pick(st)``.  ``inp`` is a dict
+    of per-cell arrays (see ``_run_scan_bucket``; duplicate-mode buckets
+    pass the copy-axis fields already tiled, see :func:`_event_loop`).
+    :func:`_event_loop` vmaps both over the batch and runs them on the
+    packed ``(clk, ctr)`` plane pair -- two contiguous tensors -- with the
+    per-segment dict view reconstructed by static slicing, so XLA fuses the
+    whole step into a handful of kernels instead of threading ~20 small
+    carry arrays.
 
     The carry is assembled as an **ordered pipeline of feature-flagged
     segments** (see ``_CARRY_SEGMENTS``): base slots/queue/channel state,
@@ -1104,17 +1106,17 @@ def _scan_cell_kernel(clk, ctr, inp, *, n_nodes, n_slots, window, freeze,
     newly-activated node drains the global queue through repeated
     activation-dispatch events.  Event precedence at equal times is kill,
     arrival, completion, re-arrival, activation, tick (kills are scheduled
-    before the burst in the reference, ticks after).  The step count
-    ``n_steps`` must cover 2n plus the dynamics budget (see
-    ``_ScanCell.dyn_budget``); the caller verifies the returned completion
-    count.
+    before the burst in the reference, ticks after).  The loop's step
+    budget ``n_steps`` (:func:`_event_loop`) must cover 2n plus the dynamics
+    budget (see ``_ScanCell.dyn_budget``); the caller verifies the returned
+    completion count.
 
     ``stream=True`` compiles the **chunked-stream** variant used by
-    :mod:`repro.core.streamscan`: the scan stops *freezing the carry* at the
-    chunk horizon ``t_stop`` (every event at ``now >= t_stop`` defers to the
-    next chunk, whose candidate stack replays the same precedence), the
-    final ``(clk, ctr)`` planes are returned so the host can hand the carry
-    off into the next chunk's tensors, and three chunk-local indirections
+    :mod:`repro.core.streamscan`: the cell goes idle at the chunk horizon
+    ``t_stop`` (every event at ``now >= t_stop`` defers to the next chunk,
+    whose candidate stack replays the same precedence), the final
+    ``(clk, ctr)`` planes are returned so the host can hand the carry off
+    into the next chunk's tensors, and three chunk-local indirections
     replace whole-stream lookups: the pull head-window validity test reads
     the chunk-rebased ``qcnt`` carry instead of the cumulative ``narr``,
     the per-function event lists arrive in CSR form (``fnev``/``fnst``,
@@ -1124,7 +1126,6 @@ def _scan_cell_kernel(clk, ctr, inp, *, n_nodes, n_slots, window, freeze,
     Dispatch records are returned raw for every mode (the host resolves
     last-wins across chunks).
     """
-    import jax
     import jax.numpy as jnp
 
     t_arr = inp["t"]
@@ -1166,20 +1167,11 @@ def _scan_cell_kernel(clk, ctr, inp, *, n_nodes, n_slots, window, freeze,
     if pool:
         fmb, pmem, pcmb = inp["fmb"], inp["pmem"], inp["pcmb"]
         row_ids = jnp.arange(pool_w)
-    if dup:
-        # duplicate-mode copy axis, flattened into the request axis: queue
-        # entry q = c*(n+1) + j is copy c of request j, so every frozen-
-        # queue structure below (pend/fprio/node_of/qseq, slot back-refs)
-        # works unchanged on the widened axis.  Static per-entry features
-        # are shared across a request's copies by tiling.
-        nq = n_copies * (n + 1)
-        fnid = jnp.tile(fnid, n_copies)
-        p = jnp.tile(p, n_copies)
-        cost = jnp.tile(cost, n_copies)
-        cnt = jnp.tile(cnt, n_copies)
-        home0 = jnp.tile(home0, n_copies)
-    else:
-        nq = n + 1
+    # duplicate-mode copy axis, flattened into the request axis: queue entry
+    # q = c*(n+1) + j is copy c of request j, so every frozen-queue structure
+    # below (pend/fprio/node_of/qseq, slot back-refs) works unchanged on the
+    # widened axis; the static per-entry features arrive tiled
+    nq = n_copies * (n + 1) if dup else n + 1
     req_ids = jnp.arange(nq)
     if dyn:
         interval, thr, delay, detect, auto_f = (inp["dynp"][k]
@@ -1222,11 +1214,60 @@ def _scan_cell_kernel(clk, ctr, inp, *, n_nodes, n_slots, window, freeze,
             def _res_seq(i):
                 return i
 
+    def pick(st):
+        """The cell's next event: the earliest of its candidate times."""
+        t_a = t_arr[st["ai"]]
+        flat = st["fin_s"].reshape(-1)
+        kflat = jnp.argmin(flat)
+        t_c = flat[kflat]
+        if dyn:
+            cand_l = [jnp.min(st["killq"]), t_a, t_c, jnp.min(st["rearr"]),
+                      jnp.min(jnp.where(st["act_pend"], st["act_t"], inf)),
+                      st["next_tick"]]
+            if hedge:
+                # hedge deadlines rank last at exact ties (measure-zero:
+                # deadlines are estimate multiples)
+                cand_l.append(jnp.min(st["hedge_t"]))
+            cand = jnp.stack(cand_l)
+        elif hedge:
+            # hedge deadlines rank after completions at exact ties (a
+            # measure-zero case: deadlines are estimate multiples)
+            cand = jnp.stack([t_a, t_c, jnp.min(st["hedge_t"])])
+        elif res:
+            # timeout fires rank after completions and retry re-arrivals
+            # after both; the reference heap would fire a timeout watch
+            # first at a deadline == completion exact tie (lower schedule
+            # seq), but deadlines are estimate multiples and re-arrivals
+            # jittered backoff sums -- measure-zero, like hedge
+            cand = jnp.stack([t_a, t_c, jnp.min(st["to_t"]),
+                              jnp.min(st["rto"])])
+        elif pool_rd:
+            # the continuation of a completion's dispatch loop runs before
+            # any other event at its instant, as the reference's does
+            cand = jnp.stack([st["rdt"], t_a, t_c])
+        else:
+            cand = jnp.stack([t_a, t_c])
+        # argmin takes the *first* minimum: at equal times the stack order is
+        # the event precedence (kill < arrival <= completion < ... < tick)
+        e = jnp.argmin(cand)
+        now = cand[e]
+        none_left = jnp.isinf(now)
+        if stream:
+            # chunk horizon: every event at or past ``t_stop`` defers to the
+            # next chunk -- the carry stays exactly as it was before the
+            # next chunk's first event, and the next chunk's candidate stack
+            # replays the same same-instant precedence order
+            none_left = none_left | (now >= t_stop)
+        return e, now, none_left, kflat
+
     # XLA's CPU scatter runs a slow generic per-element path, so every
     # fixed-size state update below is a dense one-hot ``where`` instead of
     # an ``.at[]`` scatter -- the masks are tiny ((F,), (nodes, slots), ...)
     # and the elementwise chains fuse into a handful of kernels per step.
-    def step(st, _):
+    # A step with ``none_left`` changes nothing but the ``stepc``/``stp``
+    # push stamps, which only order pushes against each other.
+    def step(st, ev):
+        e, now, none_left, kflat = ev
         ai = st["ai"]
         head = st["head"]
         fin_s, idx_s = st["fin_s"], st["idx_s"]
@@ -1244,50 +1285,9 @@ def _scan_cell_kernel(clk, ctr, inp, *, n_nodes, n_slots, window, freeze,
             wst, ndn = st["wst"], st["ndn"]
             maxa = rrt_p[0].astype(jnp.int32)
             on_to, on_sh = rrt_p[4] > 0, rrt_p[5] > 0
-
-        t_a = t_arr[ai]
-        flat = fin_s.reshape(-1)
-        kflat = jnp.argmin(flat)
-        t_c = flat[kflat]
         if dyn:
             act_t, dead, killq = st["act_t"], st["dead"], st["killq"]
             act_pend, rearr = st["act_pend"], st["rearr"]
-            cand_l = [jnp.min(killq), t_a, t_c, jnp.min(rearr),
-                      jnp.min(jnp.where(act_pend, act_t, inf)),
-                      st["next_tick"]]
-            if hedge:
-                # hedge deadlines rank last at exact ties (measure-zero:
-                # deadlines are estimate multiples)
-                cand_l.append(jnp.min(st["hedge_t"]))
-            cand = jnp.stack(cand_l)
-        elif hedge:
-            # hedge deadlines rank after completions at exact ties (a
-            # measure-zero case: deadlines are estimate multiples)
-            cand = jnp.stack([t_a, t_c, jnp.min(st["hedge_t"])])
-        elif res:
-            # timeout fires rank after completions and retry re-arrivals
-            # after both; the reference heap would fire a timeout watch
-            # first at a deadline == completion exact tie (lower schedule
-            # seq), but deadlines are estimate multiples and re-arrivals
-            # jittered backoff sums -- measure-zero, like hedge
-            cand = jnp.stack([t_a, t_c, jnp.min(to_t), jnp.min(rto)])
-        elif pool_rd:
-            # the continuation of a completion's dispatch loop runs before
-            # any other event at its instant, as the reference's does
-            cand = jnp.stack([st["rdt"], t_a, t_c])
-        else:
-            cand = jnp.stack([t_a, t_c])
-        # argmin takes the *first* minimum: at equal times the stack order is
-        # the event precedence (kill < arrival <= completion < ... < tick)
-        e = jnp.argmin(cand)
-        now = cand[e]
-        none_left = jnp.isinf(now)
-        if stream:
-            # chunk horizon: every event at or past ``t_stop`` defers to the
-            # next chunk -- the carry freezes exactly as it was before the
-            # next chunk's first event, and the next chunk's candidate stack
-            # replays the same same-instant precedence order
-            none_left = none_left | (now >= t_stop)
         off = 1 if dyn or pool_rd else 0
         do_arr = (e == off) & ~none_left
         do_comp = (e == off + 1) & ~none_left
@@ -2099,7 +2099,7 @@ def _scan_cell_kernel(clk, ctr, inp, *, n_nodes, n_slots, window, freeze,
             nxt.update(qcnt=qcnt)
         return nxt, out
 
-    # the scan carry is the packed (clk, ctr) plane pair; the dict view the
+    # the loop carry is the packed (clk, ctr) plane pair; the dict view the
     # step works on is reconstructed by static slicing, which XLA folds into
     # the step body (the unpack/update/pack chain fuses away)
     layout = _carry_layout(inp, n_nodes=n_nodes, n_slots=n_slots,
@@ -2107,19 +2107,103 @@ def _scan_cell_kernel(clk, ctr, inp, *, n_nodes, n_slots, window, freeze,
                            dyn=dyn, het=het, hedge=hedge, cold=cold,
                            dup=dup, n_copies=n_copies, fc_ring=fc_ring,
                            res=res, stream=stream, pool_w=pool_w)
+    return layout, pick, step
 
-    def plane_step(planes, x):
-        nxt, rec = step(layout.unpack(*planes), x)
-        return layout.pack(nxt), rec
 
-    (clk, ctr), (j_s, es_s, fs_s, pj_s, kd_s) = jax.lax.scan(
-        plane_step, (clk, ctr), None, length=n_steps)
+# the per-request inputs a duplicate-mode bucket shares across its copies
+_COPY_TILED = ("fnid", "p", "cost", "cnt", "home0")
+
+
+def _event_loop(clk, ctr, inp, *, n_steps, **static):
+    """The jnp event step of a whole chunk: one loop over the batch whose
+    body is :func:`_cell_step` vmapped over the cells.  It runs at most
+    ``n_steps`` steps and stops as soon as no cell of the chunk has an event
+    left (each cell's own ``none_left``: every candidate +inf, or at or past
+    a stream chunk's horizon).  Nothing revives an idle cell and its step is
+    a no-op, so the early stop changes no result; it only leaves the
+    ``stepc``/``stp`` push stamps short of the steps a longer loop runs.
+
+    The loop is outside the vmap: a per-cell ``while_loop`` would batch
+    into a ``select`` of the whole carry, record buffers included, at every
+    step.  Step ``i`` writes its records into row ``i`` of ``(n_steps, B)``
+    buffers preallocated at the sentinel request ``n``, so the rows of steps
+    that never ran land where an idle step's would.  Returns what
+    :func:`_cell_summary` returns, batched, with the loop's step count (an
+    int32 scalar, the same for every cell slot) under ``"steps"`` in the
+    summary dict; stream chunks return the final planes and the raw
+    records."""
+    import jax
+    import jax.numpy as jnp
+
+    step_inp = inp
+    if static["dup"]:
+        # tiled once here: the loop body would otherwise rebuild them
+        step_inp = dict(inp, **{k: jnp.tile(inp[k], (1, static["n_copies"]))
+                                for k in _COPY_TILED})
+    cell = partial(_cell_step, **static)
+
+    def first(clk, ctr, inp):
+        layout, pick, _ = cell(inp)
+        return pick(layout.unpack(clk, ctr))
+
+    def advance(clk, ctr, ev, inp):
+        layout, pick, step = cell(inp)
+        nxt, rec = step(layout.unpack(clk, ctr), ev)
+        clk, ctr = layout.pack(nxt)
+        return clk, ctr, rec, pick(layout.unpack(clk, ctr))
+
+    advance_b = jax.vmap(advance)
+    ev = jax.vmap(first)(clk, ctr, step_inp)
+    n = inp["t"].shape[1] - 1
+    specs = jax.eval_shape(advance_b, clk, ctr, ev, step_inp)[2]
+    recs = tuple(jnp.full((n_steps,) + s.shape, n if k == 0 else 0, s.dtype)
+                 for k, s in enumerate(specs))
+
+    def more(c):
+        i, live = c[:2]
+        return (i < n_steps) & live
+
+    def body(c):
+        i, _, clk, ctr, ev, recs = c
+        clk, ctr, rec, ev = advance_b(clk, ctr, ev, step_inp)
+        recs = tuple(jax.lax.dynamic_update_index_in_dim(r, x, i, 0)
+                     for r, x in zip(recs, rec))
+        # the idle test fuses into the step; the condition reads a scalar
+        return i + 1, ~jnp.all(ev[2]), clk, ctr, ev, recs
+
+    steps, _, clk, ctr, _, recs = jax.lax.while_loop(
+        more, body, (jnp.int32(0), ~jnp.all(ev[2]), clk, ctr, ev, recs))
+    recs = tuple(r.T for r in recs)
+    out = jax.vmap(partial(_cell_summary, **static))(clk, ctr, recs, inp)
+    if not static.get("stream"):
+        out[-1]["steps"] = steps
+    return out
+
+
+def _cell_summary(clk, ctr, recs, inp, *, n_nodes, n_slots, window, freeze,
+                  fc_push, dyn, het, hedge, cold, dup, n_copies, fc_ring,
+                  res=False, stream=False, pool_w=0, **_):
+    """One cell's results from its final ``(clk, ctr)`` planes and its
+    ``(j, start, finish, prio, node)`` step records (vmapped by
+    :func:`_event_loop`): per-request arrays and the counters the host
+    unpacks, in the form each feature set needs."""
+    import jax.numpy as jnp
+
+    j_s, es_s, fs_s, pj_s, kd_s = recs
     if stream:
         # chunked-stream mode: the host handoff needs the final carry
         # planes (everything a summary would report lives in them) plus the
         # raw dispatch records -- last-wins resolution across re-dispatches
         # happens host-side in global chunk order for every feature set
-        return (clk, ctr), (j_s, es_s, fs_s, pj_s, kd_s)
+        return (clk, ctr), recs
+    n = inp["t"].shape[0] - 1
+    pool = cold and pool_w > 0
+    pool_rd = pool and freeze
+    layout = _carry_layout(inp, n_nodes=n_nodes, n_slots=n_slots,
+                           window=window, freeze=freeze, fc_push=fc_push,
+                           dyn=dyn, het=het, hedge=hedge, cold=cold,
+                           dup=dup, n_copies=n_copies, fc_ring=fc_ring,
+                           res=res, stream=stream, pool_w=pool_w)
     state = layout.unpack(clk, ctr)
     aux = {}
     if cold:
@@ -2355,7 +2439,7 @@ def _phase(name: str, rec: dict | None = None, key: str | None = None,
                 rec[key] += own
 
 
-# The carry of ``_scan_cell_kernel`` is an ordered pipeline of feature-flagged
+# The carry of ``_cell_step`` is an ordered pipeline of feature-flagged
 # segments: each entry names a compile flag and the carry keys the segment
 # contributes when enabled (always-on base state -- slots, queues, channel
 # clocks, estimator rings -- is not listed).  Bit i of a bucket key's leading
@@ -2530,22 +2614,27 @@ def _alloc_bucket_inputs(shape_key: tuple, bsz: int) -> dict:
 
 def _probe_inputs(shape_key: tuple, bsz: int) -> dict:
     """The idle allocation with finite ascending arrival times in rows
-    ``[:n_b]`` of every cell: a timing probe that runs the full ``2 n_b``
-    step budget on either step path, as a loaded bucket of that shape does
-    (a step's cost does not depend on the values it steps over)."""
+    ``[:n_b]`` of every cell: a timing probe whose every batch slot steps
+    as a loaded bucket of that shape does (a step's cost does not depend on
+    the values it steps over).  The Pallas kernel runs its ``2 n_b`` steps;
+    on the jnp loop no call starts (the allocation has no cores), so it
+    stops after the ``n_b`` arrival steps."""
     inp = _alloc_bucket_inputs(shape_key, bsz)
     n_b = shape_key[1]
     inp["t"][:, :n_b] = np.arange(n_b)
     return inp
 
 
-def _exec_steps(chunk: list, bsz: int, n_steps: int, pallas: bool) -> int:
-    """Event steps one dispatched chunk runs: on the Pallas kernel each
-    cell's own arrival and completion steps (a padded cell none), on the
-    vmapped jnp scan the full ``n_steps`` budget in every batch slot."""
+def _exec_steps(chunk: list, bsz: int, summary: dict, pallas: bool) -> int:
+    """Event steps one chunk ran, read once its results are back: on the
+    Pallas kernel each cell's own arrival and completion steps (a padded
+    cell none); on the jnp loop (:func:`_event_loop`) every batch slot for
+    the loop's own count, ``summary["steps"]``, which stops once no cell of
+    the chunk has an event left and never passes the ``n_steps`` budget (a
+    chunk of padding cells runs none)."""
     if pallas:
         return 2 * sum(len(c.feats.t) for c in chunk)
-    return bsz * n_steps
+    return bsz * int(summary["steps"])
 
 
 def _runner_kwargs(shape_key: tuple) -> tuple[dict, dict]:
@@ -3106,8 +3195,6 @@ def _run_scan_bucket(key: tuple, cells: list[_ScanCell]) -> list[tuple]:
             _STEP_COUNTS["pool_node_slots"] += bsz * nodes_b
             _STEP_COUNTS["pool_rows"] += bsz * nodes_b * pool_w
         _STEP_COUNTS["call_steps"] += 2 * sum(len(c.feats.t) for c in chunk)
-        _STEP_COUNTS["exec_steps"] += _exec_steps(chunk, bsz,
-                                                  2 * n_b + xtra_now, pallas)
         with _phase("dispatch", rec, "dispatch_s"), _x64_ctx(use64):
             # float64 buckets convert inputs *inside* enable_x64 --
             # quantizing kill/arrival/deadline times through float32 first
@@ -3117,11 +3204,19 @@ def _run_scan_bucket(key: tuple, cells: list[_ScanCell]) -> list[tuple]:
             clk, ctr = init_c(arrs)
             return scan_c(clk, ctr, arrs)
 
-    def _collect(res, rec: dict):
+    def _collect(res, rec: dict, chunk: list, bsz: int):
         """Block on one dispatched chunk, then copy its results back."""
         with _phase("wait", rec, "wait_s"):
             jax.block_until_ready(res)
-        return jax.tree_util.tree_map(np.asarray, res)
+        return _copy_back(res, chunk, bsz)
+
+    def _copy_back(res, chunk: list, bsz: int):
+        """One chunk's results on the host, its executed steps counted."""
+        res = jax.tree_util.tree_map(np.asarray, res)
+        summary = res[1] if dyn or resil else res[4]
+        _STEP_COUNTS["exec_steps"] += _exec_steps(chunk, bsz, summary,
+                                                  pallas)
+        return res
 
     def _finish(lo: int, chunk: list, inp: dict, res, rec: dict) -> None:
         """Host-sync one in-flight chunk, verify hedge step budgets
@@ -3138,7 +3233,8 @@ def _run_scan_bucket(key: tuple, cells: list[_ScanCell]) -> list[tuple]:
 
     def _sync(chunk: list, inp: dict, res, rec: dict):
         """Copy one chunk's results back and check its step budgets."""
-        res = jax.tree_util.tree_map(np.asarray, res)
+        bsz = inp["cores"].shape[0]
+        res = _copy_back(res, chunk, bsz)
         if hedge:
             ndone_b = (res[1] if dyn else res[4])["ndone"]
             if any(int(ndone_b[b]) != len(chunk[b].feats.t)
@@ -3149,7 +3245,8 @@ def _run_scan_bucket(key: tuple, cells: list[_ScanCell]) -> list[tuple]:
                 # construction
                 full = max(c.dyn_budget() + c.hedge_budget_full()
                            for c in chunk)
-                res = _collect(_dispatch(inp, _pow2(full), rec, chunk), rec)
+                res = _collect(_dispatch(inp, _pow2(full), rec, chunk), rec,
+                               chunk, bsz)
                 ndone_b = (res[1] if dyn else res[4])["ndone"]
                 for b, cell in enumerate(chunk):
                     if int(ndone_b[b]) != len(cell.feats.t):
@@ -3168,7 +3265,8 @@ def _run_scan_bucket(key: tuple, cells: list[_ScanCell]) -> list[tuple]:
                 # only fires on a genuine kernel budget bug
                 full = max(c.dyn_budget() + c.hedge_budget()
                            + c.res_budget_full() for c in chunk)
-                res = _collect(_dispatch(inp, _pow2(full), rec, chunk), rec)
+                res = _collect(_dispatch(inp, _pow2(full), rec, chunk), rec,
+                               chunk, bsz)
         if pool_rd:
             pdone_b = res[4]["pdone"]
             if any(int(pdone_b[b]) != len(chunk[b].feats.t)
@@ -3177,7 +3275,8 @@ def _run_scan_bucket(key: tuple, cells: list[_ScanCell]) -> list[tuple]:
                 # heads released many calls at once): re-run the chunk at
                 # the strict bound
                 full = max(c.pool_budget_full() for c in chunk)
-                res = _collect(_dispatch(inp, _pow2(full), rec, chunk), rec)
+                res = _collect(_dispatch(inp, _pow2(full), rec, chunk), rec,
+                               chunk, bsz)
                 pdone_b = res[4]["pdone"]
                 for b, cell in enumerate(chunk):
                     if int(pdone_b[b]) != len(cell.feats.t):
@@ -3755,7 +3854,7 @@ def simulate_cluster_scan(
 
 
 class ScanBackend:
-    """Batched jax.lax.scan variant of the ours-mode simulator.
+    """Batched event-step loop variant of the ours-mode simulator.
 
     Supports single nodes *and* clusters: any of the five policies under the
     pull assignment or the push assignment (FC via per-(node, fn) count

@@ -54,7 +54,7 @@ def retry_jitter_u(seq: int, attempt: int) -> float:
     products stay far below 2^31 for any realistic burst, and the result
     is a 16-bit integer over 65536 -- exactly representable in float, so
     reference-python and jnp-float64 agree bit-for-bit.  Keep in sync with
-    the ``res`` segment of ``fastpath._scan_cell_kernel``."""
+    the ``res`` segment of ``fastpath._cell_step``."""
     h = (seq * 7919 + attempt * 104729 + 12345) % 65536
     return h / 65536.0
 

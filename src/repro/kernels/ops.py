@@ -8,8 +8,6 @@ references execute.  ``force`` overrides for kernel validation tests
 
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 
 from . import ref
@@ -60,13 +58,15 @@ def event_step(clk, ctr, inp, *, force: str | None = None,
     ``clk``/``ctr`` are the ``(B, len_f)`` / ``(B, len_i)`` packed carry
     plane pairs (see ``repro.core.fastpath._PlaneLayout``) and ``inp`` a
     dict of batched per-cell input arrays; ``static`` carries the kernel's
-    compile-time shape/feature kwargs.  Returns what the per-cell scan
-    kernel returns, batched.
+    compile-time shape/feature kwargs.  Returns the per-cell results,
+    batched (see ``repro.core.fastpath._cell_summary``).
 
-    The pure-jnp oracle (a vmap over ``_scan_cell_kernel``) *is* the fused
-    CPU path -- XLA fuses the plane unpack/update/pack chain into the step
-    body.  On TPU the base pull configuration runs as a Pallas megakernel
-    with the carry planes resident in VMEM across the scan
+    The pure-jnp oracle (``repro.core.fastpath._event_loop``: one loop over
+    the batch, its body the per-cell step vmapped, stopped once every cell
+    is idle) *is* the fused CPU path -- XLA fuses the plane
+    unpack/update/pack chain into the step body.  On TPU the base pull
+    configuration runs as a Pallas megakernel with the carry planes
+    resident in VMEM across the scan
     (``repro.kernels.event_step``); unsupported feature combinations fall
     back to the oracle unless ``force="pallas"``."""
     from ..core import fastpath as _fp     # lazy: core is heavy
@@ -81,7 +81,7 @@ def event_step(clk, ctr, inp, *, force: str | None = None,
         return event_step_pallas(clk, ctr, inp,
                                  interpret=interpret or not _on_tpu(),
                                  **static)
-    return jax.vmap(partial(_fp._scan_cell_kernel, **static))(clk, ctr, inp)
+    return _fp._event_loop(clk, ctr, inp, **static)
 
 
 def event_step_path(force: str | None = None, **static) -> str:

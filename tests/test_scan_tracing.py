@@ -11,8 +11,9 @@ Contracts under test:
   summed ``build_s``, and every record carries ``wait_s`` / ``unpack_s``;
 * self times are non-negative and add up to no more than the entry's wall;
 * ``step_slots`` / ``call_steps`` count padded and real event steps exactly,
-  and ``exec_steps`` the steps the step runs: every slot on the jnp scan,
-  each cell's own 2n on the Pallas kernel;
+  and ``exec_steps`` the steps the step runs: on the jnp loop every batch
+  slot until the chunk's last event (none for a chunk of padding cells),
+  on the Pallas kernel each cell's own 2n;
 * the step's XLA module is ``jit_event_step``, which every benchmark
   cell's ``step_pattern`` matches and the init module does not.
 """
@@ -127,12 +128,29 @@ def test_step_counters_exact():
     # steps each; bucket two: 1 cell, 128-call rows; no extra steps
     assert totals["step_slots"] == 4 * 2 * 64 + 1 * 2 * 128
     assert totals["call_steps"] == 2 * (40 + 50 + 60 + 100)
-    # the vmapped jnp scan runs every slot it is dispatched
-    assert totals["exec_steps"] == totals["step_slots"]
+    # the jnp loop stops at each chunk's last event: push cells take one
+    # arrival and one completion step a call, so bucket one runs its 4
+    # slots for the 60-call cell's 120 steps and bucket two 200
+    assert totals["exec_steps"] == 4 * 2 * 60 + 1 * 2 * 100
+    assert totals["exec_steps"] < totals["step_slots"]
     scan_timings_clear()
     assert scan_phase_totals() == {"step_slots": 0, "call_steps": 0,
                                    "exec_steps": 0, "pool_node_slots": 0,
                                    "pool_rows": 0, "pool_peak": 0}
+
+
+def test_padding_chunk_runs_no_steps():
+    # the idle allocation is a chunk of padding cells: every arrival is
+    # +inf, so the jnp loop stops before its first step
+    key = (0, 64, 2, 2, 1, 1, 10, 1, 1, 1, 0, 0)
+    init_c, scan_c = fp._scan_runner(key + (4,))
+    arrs = {k: jax.numpy.asarray(v)
+            for k, v in fp._alloc_bucket_inputs(key, 4).items()}
+    res = jax.tree_util.tree_map(np.asarray, scan_c(*init_c(arrs), arrs))
+    assert int(res[4]["steps"]) == 0
+    assert fp._exec_steps([], 4, res[4], pallas=False) == 0
+    # no step ran, so no call has a start or a finish
+    assert not res[0].any() and not res[1].any()
 
 
 def test_exec_steps_on_the_pallas_path(monkeypatch):

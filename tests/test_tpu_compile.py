@@ -97,8 +97,19 @@ def test_jnp_bucket_runner_compiles(one_chip, monkeypatch, key):
     assert ops.event_step_path(**step_kw) == "jnp"
     bsz = 1 if fp._mask_features(key[0])["stream"] else 2
     scan_c = _compile_runner(key, bsz, one_chip)
-    assert "tpu_custom_call" not in scan_c.as_text()
-    assert "f64" in scan_c.as_text()       # the float64 family stays f64
+    text = scan_c.as_text()
+    assert "tpu_custom_call" not in text
+    assert "f64" in text                   # the float64 family stays f64
+    # the step loop runs at batch level: one while over the chunk carries
+    # the (n_steps, batch) record buffers, and no select copies them (a
+    # per-cell loop batched by vmap selects its whole carry every step;
+    # those selects carry the batched while's op name)
+    rec = [f"[{step_kw['n_steps']},{bsz}]", f"[{bsz},{step_kw['n_steps']}]"]
+    lines = text.splitlines()
+    assert any(" while(" in ln and rec[0] in ln for ln in lines)
+    assert not [ln for ln in lines
+                if " select(" in ln and '/while"' in ln
+                and any(r in ln for r in rec)]
 
 
 def test_pallas_kernel_compiles_outside_the_runner(one_chip):
