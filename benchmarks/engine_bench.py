@@ -1,14 +1,5 @@
-"""Beyond-paper: the policies on the REAL JAX serving engine (tiny models),
-plus the simulation-backend speedup rows.
-
-Mixed cheap/heavy endpoints under a burst; SEPT/FC should cut mean response
-vs FIFO exactly as in the simulator -- but with actual XLA execution.
-
-The policy grid is declared as a SweepSpec like every simulator benchmark,
-and runs through a custom cell runner.  XLA runtimes do not survive a fork,
-so these cells execute in-process by default; ``--workers N`` fans them out
-over a **spawn**-based pool instead (``run_sweep(executor="spawn")``), each
-worker paying its own XLA warm-up but running concurrently.
+"""Beyond-paper: the simulation-backend speedup rows and the scan path's
+sweep, stream and capability rows.
 
 ``backend_speedup_rows`` times the simulation engines themselves on a
 high-intensity sweep grid (workload generation and metric aggregation are
@@ -40,7 +31,6 @@ cells/sec ratio over the legacy per-cell pipeline."""
 import json
 import time
 from dataclasses import replace
-from functools import partial
 
 from .common import emit
 
@@ -54,11 +44,6 @@ from repro.core import (
     simulate_single_node,
 )
 from repro.core.sweep import make_workload, run_cell
-
-
-def spec() -> SweepSpec:
-    # quick mode shrinks the per-cell burst (see _engine_cell), not the grid
-    return SweepSpec(policies=("fifo", "sept", "fc"), seeds=1)
 
 
 def speedup_spec(quick: bool = False) -> SweepSpec:
@@ -545,6 +530,37 @@ def mega_spec(quick: bool = False) -> SweepSpec:
                      workload_cores=16, backends=("scan",))
 
 
+def analyse_scan_buckets(recs: list[dict]) -> list[dict]:
+    """Roofline-style breakdown for the simulator's scan path: aggregate the
+    per-dispatch timing records from ``repro.core.scan_bucket_timings()``
+    into one row per bucket shape, splitting the wall into host build (row
+    fill), XLA compile, async dispatch (enqueue) and host sync (the block on
+    results), with the dominant term named."""
+    by_bucket: dict[str, dict] = {}
+    for r in recs:
+        agg = by_bucket.setdefault(r["bucket"], {
+            "bucket": r["bucket"], "bsz": r["bsz"], "cells": 0,
+            "chunks": 0, "build_s": 0.0, "compile_s": 0.0,
+            "dispatch_s": 0.0, "sync_s": 0.0, "tune_s": 0.0})
+        agg["cells"] += r["cells"]
+        agg["chunks"] += 1 if r["cells"] else 0   # tune records aren't chunks
+        agg["bsz"] = max(agg["bsz"], r["bsz"])
+        for k in ("build_s", "compile_s", "dispatch_s", "sync_s"):
+            agg[k] += r[k]
+        agg["tune_s"] += r.get("tune_s", 0.0)
+    out = []
+    for agg in by_bucket.values():
+        terms = {k: agg[k] for k in ("build_s", "compile_s",
+                                     "dispatch_s", "sync_s", "tune_s")}
+        agg["dominant"] = max(terms, key=terms.get)
+        agg["total_s"] = sum(terms.values())
+        agg["cells_per_s"] = (agg["cells"] / agg["total_s"]
+                              if agg["total_s"] > 0 else 0.0)
+        out.append(agg)
+    out.sort(key=lambda a: -a["total_s"])
+    return out
+
+
 def mega_rows(quick: bool = False,
               artifacts: str | None = None) -> list[dict]:
     """The fused-path headline: run the mega grid through
@@ -561,7 +577,6 @@ def mega_rows(quick: bool = False,
                  "derived": "skipped=no-jax"}]
     from repro.core import scan_bucket_timings, scan_timings_clear
     from repro.core.sweep import CLUSTER_XCHECK_RTOL
-    from .roofline import analyse_scan_buckets
 
     cells = mega_spec(quick).cells()
 
@@ -859,35 +874,6 @@ def storm_rows(quick: bool = False, artifacts: str | None = None,
     return rows
 
 
-def _engine_cell(cell: SweepCell, quick: bool = False) -> dict:
-    """One policy on the live engine; returns sweep-shaped metrics."""
-    from repro.configs import get_config
-    from repro.models import scale_down
-    from repro.serving import Endpoint, ServingEngine
-
-    n_cheap, n_heavy = (6, 3) if quick else (16, 6)
-    cheap_cfg = scale_down(get_config("qwen3_1_7b"))
-    heavy_cfg = scale_down(get_config("deepseek_7b"), layers=4,
-                           d_model=128, d_ff=256)
-    eng = ServingEngine(
-        [Endpoint("cheap", cheap_cfg, prompt_len=2, gen_len=2),
-         Endpoint("heavy", heavy_cfg, prompt_len=4, gen_len=32)],
-        slots=2, policy=cell.policy)
-    for _ in range(3):          # seed the estimator
-        eng.submit("cheap"); eng.submit("heavy")
-    eng.run(max_wall_s=120)
-    eng.completed.clear()
-    for i in range(max(n_cheap, n_heavy)):
-        if i < n_cheap:
-            eng.submit("cheap")
-        if i < n_heavy:
-            eng.submit("heavy")
-    eng.run(max_wall_s=240)
-    s = eng.summary()
-    return {"R_avg": s["R_avg"], "R_p50": s["R_p50"], "R_p95": s["R_p95"],
-            "n": float(s["n"])}
-
-
 # --------------------------------------------------------------------------
 # planet: the million-invocation streaming frontier (ISSUE 9)
 # --------------------------------------------------------------------------
@@ -1145,40 +1131,18 @@ def _write_bench_trajectory(fname: str, row: str, **metrics) -> None:
     path.write_text(json.dumps(payload, indent=1, sort_keys=True) + "\n")
 
 
-ROW_GROUPS = ("all", "engine", "backend", "cluster", "frontier",
-              "straggler", "matrix", "mega", "storm", "planet")
+ROW_GROUPS = ("all", "backend", "cluster", "frontier", "straggler",
+              "matrix", "mega", "storm", "planet")
 
 
 def run(quick: bool = False, backend: str = "vectorized",
-        workers: int | None = None, rows_group: str = "all",
-        artifacts: str | None = None) -> list[dict]:
+        rows_group: str = "all", artifacts: str | None = None) -> list[dict]:
     rows = []
 
     def _group(name: str, new_rows: list[dict]) -> None:
         rows.extend(new_rows)
         _write_bench_summary(name, new_rows)
 
-    if rows_group in ("all", "engine"):
-        # XLA engines cannot fork; workers>1 uses a spawn pool so the
-        # cells run concurrently, each worker with its own runtime --
-        # unless this process holds an accelerator, where run_sweep runs
-        # them in-process (one process per chip).  Importing JAX here is
-        # what lets run_sweep see the accelerator before any child starts
-        import jax  # noqa: F401
-        result = run_sweep(spec(), workers=workers or 1,
-                           runner=partial(_engine_cell, quick=quick),
-                           executor="spawn" if (workers or 1) > 1 else None)
-        engine_rows = []
-        for cr in result.results:
-            m = cr.metrics
-            engine_rows.append({
-                "name": f"engine/{cr.cell.policy}",
-                "us_per_call": m["R_avg"] * 1e6,
-                "derived": (f"R_p50={m['R_p50']*1e3:.0f}ms;"
-                            f"R_p95={m['R_p95']*1e3:.0f}ms;n={m['n']:.0f};"
-                            f"workers={result.workers}"),
-            })
-        _group("engine", engine_rows)
     if rows_group in ("all", "backend"):
         _group("backend", backend_speedup_rows(quick, backend=backend))
     if rows_group in ("all", "cluster"):
@@ -1199,11 +1163,10 @@ def run(quick: bool = False, backend: str = "vectorized",
 
 
 def main(quick: bool = False, backend: str = "vectorized",
-         workers: int | None = None, rows_group: str = "all",
-         json_path: str | None = None,
+         rows_group: str = "all", json_path: str | None = None,
          artifacts: str | None = None) -> None:
-    rows = run(quick, backend=backend, workers=workers,
-               rows_group=rows_group, artifacts=artifacts)
+    rows = run(quick, backend=backend, rows_group=rows_group,
+               artifacts=artifacts)
     emit(rows)
     if json_path:
         with open(json_path, "w") as fh:
@@ -1217,9 +1180,6 @@ if __name__ == "__main__":
     ap.add_argument("--backend", default="vectorized",
                     choices=("vectorized", "scan"),
                     help="fast backend for the speedup row")
-    ap.add_argument("--workers", type=int, default=None,
-                    help="spawn-based pool size for the engine cells "
-                         "(XLA cannot fork; >1 uses executor='spawn')")
     ap.add_argument("--rows", default="all", choices=ROW_GROUPS,
                     help="which benchmark rows to run")
     ap.add_argument("--json", default=None, metavar="PATH",
@@ -1227,6 +1187,5 @@ if __name__ == "__main__":
     ap.add_argument("--artifacts", default=None, metavar="DIR",
                     help="directory for the frontier CSV/plot artifacts")
     args = ap.parse_args()
-    main(args.quick, backend=args.backend, workers=args.workers,
-         rows_group=args.rows, json_path=args.json,
-         artifacts=args.artifacts)
+    main(args.quick, backend=args.backend, rows_group=args.rows,
+         json_path=args.json, artifacts=args.artifacts)

@@ -1,4 +1,4 @@
-"""Benchmark driver: one module per paper table/figure + roofline.
+"""Runs the benchmarks: one module per paper table/figure, plus the scan rows.
 
 Prints the harness CSV contract ``name,us_per_call,derived`` for every row.
 ``--quick`` shrinks seed counts / grids for smoke runs.
@@ -14,7 +14,6 @@ from . import (
     fig2_cold_starts,
     fig5_fairness,
     fig6_multinode,
-    roofline,
     table1_functions,
     table2_completion,
     table3_response_stretch,
@@ -31,7 +30,6 @@ MODULES = [
     ("fig6", fig6_multinode),
     ("trace", trace_replay),
     ("engine", engine_bench),
-    ("roofline", roofline),
 ]
 
 

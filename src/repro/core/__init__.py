@@ -14,8 +14,8 @@ Simulation backends (``simulate_single_node(..., backend=...)`` and the
   - ``"reference"`` -- the discrete-event loop; supports every scenario and
     defines the semantics.
   - ``"vectorized"`` -- array fast path for the ours-mode single node
-    (``core/fastpath.py``); ~10x faster and **exact** (bit-identical
-    metrics), including cold starts and tight-memory eviction.
+    (``core/fastpath.py``); **exact** (bit-identical metrics to the
+    reference), including cold starts and tight-memory eviction.
   - ``"scan"`` -- batched ``jax.lax.scan`` variant; a whole grid runs as one
     scan over a padded request tensor (``run_cells_scan``).  Covers every
     ours-mode regime: always-warm cells (``scan_eligible``) in float32

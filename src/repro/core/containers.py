@@ -18,10 +18,6 @@ Two admission disciplines:
     function (bounded by #cores each), so with RAM ≥ #fns × cores × size the
     eviction count -- and therefore measured cold starts -- drops to ≈0
     (paper Fig. 2b: flat from 32 GB).
-
-In the TPU serving engine the same class tracks *resident endpoint state*
-(compiled program + weights + KV slab) against the HBM pool; only the cost
-constants change (see serving/engine.py).
 """
 
 from __future__ import annotations

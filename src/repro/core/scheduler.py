@@ -4,16 +4,16 @@ This is the component that replaces the stock OpenWhisk invoker logic:
 
   * the simple FIFO queue is replaced by a :class:`PriorityQueue` whose
     priorities come from a pluggable :class:`Policy` (FIFO/SEPT/EECT/RECT/FC);
-  * admission is **CPU-based**: at most ``slots`` (= CPU cores / decode slots)
+  * admission is **CPU-based**: at most ``slots`` (= CPU cores)
     calls execute concurrently, each on a dedicated slot (no oversubscription,
     hence no OS preemption);
   * priorities are computed once, at enqueue time;
   * the estimator observes arrivals (for FC/RECT) and completions (for E[p]).
 
 The class is deliberately clock-agnostic: callers (the discrete-event
-simulator, or the real serving engine) own time and I/O, and drive the
-scheduler through ``receive`` / ``complete``, which return the set of calls
-that should start executing *now*.
+simulator) own time and I/O, and drive the scheduler through ``receive`` /
+``complete``, which return the set of calls that should start executing
+*now*.
 """
 
 from __future__ import annotations

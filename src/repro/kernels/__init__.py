@@ -1,9 +1,8 @@
-"""Pallas TPU kernels for the serving hot spots + pure-jnp oracles.
+"""The simulator's event-step kernels.
 
-Layout per the brief: <name>.py holds the pl.pallas_call + BlockSpec kernel,
-ops.py the jit'd dispatch wrapper, ref.py the oracles.
+``ops.py`` holds the dispatch (:func:`ops.event_step`, with
+:func:`ops.event_step_path` naming the implementation it picks): the
+Pallas megakernel of ``event_step.py`` on a TPU for the base pull
+configuration, the vmapped jnp step of ``repro.core.fastpath`` everywhere
+else.  Callers import the modules directly.
 """
-
-from .ops import decode_attention, flash_attention, rglru_scan, rwkv6_scan
-
-__all__ = ["decode_attention", "flash_attention", "rglru_scan", "rwkv6_scan"]

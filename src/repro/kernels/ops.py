@@ -1,54 +1,22 @@
-"""Jit'd dispatch wrappers: Pallas on TPU, jnp oracle elsewhere.
+"""Dispatch of the batched cluster event step: Pallas on TPU, jnp elsewhere.
 
-The model code calls these entry points; on a real TPU the Pallas kernels
-run (interpret=False), on CPU (this container, and all tests) the pure-jnp
-references execute.  ``force`` overrides for kernel validation tests
-(interpret=True runs the Pallas kernel body in Python on CPU).
+:func:`event_step` is the entry every scan bucket runs through
+(``repro.core.fastpath._runner_fns`` jits it once per bucket shape).  On a
+TPU the base pull configuration runs the Pallas megakernel of
+``repro.kernels.event_step``; every other feature set, and every backend
+but the TPU, runs the vmapped jnp step (``repro.core.fastpath._event_loop``).
+:func:`event_step_path` says which of the two a feature set gets.
+``force`` overrides the choice for kernel validation tests
+(``interpret=True`` runs the Pallas kernel body in Python on CPU).
 """
 
 from __future__ import annotations
 
 import jax
 
-from . import ref
-from .decode_attention import decode_attention as _decode_pallas
-from .flash_attention import flash_attention as _flash_pallas
-from .rglru_scan import rglru_scan as _rglru_pallas
-from .rwkv6_scan import rwkv6_scan as _rwkv6_pallas
-
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
-
-
-def flash_attention(q, k, v, *, causal=True, window=-1, softmax_scale=None,
-                    force: str | None = None, interpret: bool = False):
-    """force: None (auto) | "pallas" | "ref"."""
-    use_pallas = force == "pallas" or (force is None and _on_tpu())
-    if use_pallas:
-        return _flash_pallas(q, k, v, causal=causal, window=window,
-                             softmax_scale=softmax_scale,
-                             interpret=interpret or not _on_tpu())
-    return ref.attention_ref(q, k, v, causal=causal, window=window,
-                             softmax_scale=softmax_scale)
-
-
-def decode_attention(q, k, v, lengths, *, softmax_scale=None,
-                     force: str | None = None, interpret: bool = False):
-    use_pallas = force == "pallas" or (force is None and _on_tpu())
-    if use_pallas:
-        return _decode_pallas(q, k, v, lengths, softmax_scale=softmax_scale,
-                              interpret=interpret or not _on_tpu())
-    return ref.decode_attention_ref(q, k, v, lengths,
-                                    softmax_scale=softmax_scale)
-
-
-def rglru_scan(a, gx, h0, *, force: str | None = None,
-               interpret: bool = False):
-    use_pallas = force == "pallas" or (force is None and _on_tpu())
-    if use_pallas:
-        return _rglru_pallas(a, gx, h0, interpret=interpret or not _on_tpu())
-    return ref.rglru_ref(a, gx, h0)
 
 
 def event_step(clk, ctr, inp, *, force: str | None = None,
@@ -95,16 +63,3 @@ def event_step_path(force: str | None = None, **static) -> str:
     if force == "pallas" or (_on_tpu() and event_step_supported(**static)):
         return "pallas"
     return "jnp"
-
-
-def rwkv6_scan(r, k, v, w, u, *, force: str | None = None,
-               interpret: bool = False):
-    use_pallas = force == "pallas" or (force is None and _on_tpu())
-    if use_pallas:
-        return _rwkv6_pallas(r, k, v, w, u,
-                             interpret=interpret or not _on_tpu())
-    import jax.numpy as jnp
-    s0 = jnp.zeros((r.shape[0], r.shape[2], r.shape[3], r.shape[3]),
-                   jnp.float32)
-    out, _ = ref.rwkv6_ref(r, k, v, w, u, s0)
-    return out

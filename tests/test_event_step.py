@@ -382,7 +382,7 @@ class TestObservability:
         assert scan_bucket_timings() == []
 
     def test_analyse_scan_buckets(self):
-        from benchmarks.roofline import analyse_scan_buckets
+        from benchmarks.engine_bench import analyse_scan_buckets
 
         recs = [
             {"bucket": "a", "bsz": 4, "cells": 4, "build_s": 0.1,

@@ -14,6 +14,9 @@ Contracts under test:
   discipline: a function's containers are created only while all of them
   are busy, so they never outnumber the cores, and the probe asserts it;
 * ample and memory-bound cells of one batch land in separate buckets;
+* on ample-memory nodes, where the scan runs the per-(node, fn) count
+  path, the container table forced in its place gives the same clocks and
+  counters, bit for bit;
 * what the table does not model is still refused: pull cells whose head
   could block, nodes too small for one container, and memory-bound
   streams.
@@ -35,7 +38,10 @@ from repro.core import (
     simulate_cluster,
 )
 from repro.core import containers as _containers
+from repro.core import fastpath
 from repro.core.fastpath import (
+    CLUSTER_CONTAINER_MB,
+    CLUSTER_MEMORY_MB,
     POLICY_NAMES,
     _pool_width,
     scan_cache_clear,
@@ -260,6 +266,71 @@ def test_ample_and_memory_bound_cells_take_separate_buckets():
         assert _gap(_clocks(got), _clocks(ref)) <= 1e-12
         assert _counters(got) == _counters(ref)
     assert rows[0].creations == 0 and rows[1].creations > 0
+
+
+# ample 40 GB nodes: every cold cell runs the count path unless the table
+# is forced; each layout's cells run as one batch a path
+AMPLE_LAYOUTS = {"pull": (2, 4), "push": (2, 4), "single": (1, 4)}
+AMPLE_SEEDS = (0, 1)
+
+
+def _forced_table_width(f, cores, warm, memory_mb, container_mb):
+    return 0 if warm else _pool_width(f.fns, cores, memory_mb, container_mb)
+
+
+@pytest.fixture(scope="module", params=sorted(AMPLE_LAYOUTS))
+def ample_runs(request):
+    """Every policy x seed cell of one layout at intensity 30 through the
+    reference, the scan's count path and the scan with the container table
+    forced; returns the layout and ``{(policy, seed): (reference, count,
+    table)}`` with the table rows each scan run dispatched."""
+    layout = request.param
+    nodes, cores = AMPLE_LAYOUTS[layout]
+    keys = [(p, s) for p in POLICY_NAMES for s in AMPLE_SEEDS]
+    kw = dict(memory_mb=CLUSTER_MEMORY_MB, container_mb=CLUSTER_CONTAINER_MB)
+
+    def bursts():
+        return [_burst(nodes, cores, 30, seed=s) for _, s in keys]
+
+    if layout == "single":
+        refs = [simulate_single_node(r, cores, p, warm=False, **kw)
+                for r, (p, _) in zip(bursts(), keys)]
+
+        def scan():
+            return simulate_cells_scan(
+                [(r, cores, p, False) for r, (p, _) in zip(bursts(), keys)],
+                **kw)
+    else:
+        refs = [simulate_cluster(r, nodes, cores, p, assignment=layout,
+                                 warm=False, backend="reference", **kw)
+                for r, (p, _) in zip(bursts(), keys)]
+
+        def scan():
+            return simulate_cluster_cells_scan(
+                [(r, nodes, cores, p, layout, "least_loaded", None, None,
+                  None, False) for r, (p, _) in zip(bursts(), keys)], **kw)
+
+    scan_timings_clear()
+    count = scan()
+    count_rows = scan_phase_totals()["pool_rows"]
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(fastpath, "_cell_pool_width", _forced_table_width)
+        scan_timings_clear()
+        table = scan()
+        table_rows = scan_phase_totals()["pool_rows"]
+    return layout, dict(zip(keys, zip(refs, count, table))), \
+        (count_rows, table_rows)
+
+
+@pytest.mark.parametrize("seed", AMPLE_SEEDS)
+@pytest.mark.parametrize("policy", POLICY_NAMES)
+def test_table_matches_count_path_on_ample_cells(ample_runs, policy, seed):
+    layout, runs, (count_rows, table_rows) = ample_runs
+    ref, count, table = runs[policy, seed]
+    assert count_rows == 0 and table_rows > 0, layout
+    assert _gap(_clocks(table), _clocks(count)) == 0.0
+    assert _gap(_clocks(table), _clocks(ref)) == 0.0
+    assert _counters(table) == _counters(count) == _counters(ref)
 
 
 @pytest.mark.parametrize("policy", ("sept", "fc"))
